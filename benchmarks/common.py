@@ -13,6 +13,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict
 
+import jax
 import numpy as np
 
 from repro.core.sparse import (
@@ -54,11 +55,7 @@ def time_call(fn, *args, warmup: int = 2, iters: int = 5) -> float:
     for _ in range(iters):
         t0 = time.perf_counter()
         r = fn(*args)
-        try:
-            import jax
-            jax.block_until_ready(r)
-        except Exception:
-            pass
+        jax.block_until_ready(r)
         times.append((time.perf_counter() - t0) * 1e6)
     return float(np.median(times))
 

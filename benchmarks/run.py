@@ -288,4 +288,8 @@ def main(argv=None) -> None:
 
 
 if __name__ == '__main__':
+    from repro.launch.compile_cache import enable_compile_cache
+
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    enable_compile_cache(os.path.join(repo_root, ".jax_cache"))
     main()

@@ -1,119 +1,45 @@
-"""Version-portable JAX API shims (supported range: jax 0.4.35 – 0.6.x;
-the floor is where ``jax.make_mesh`` first exists).
+"""The JAX API surface the distributed code imports (jax 0.9, Python 3.12).
 
-The repo targets a single source tree across several JAX API migrations:
-
-* ``shard_map``   — moved from ``jax.experimental.shard_map`` to ``jax``
-  itself, and its replication-check kwarg was renamed
-  ``check_rep`` → ``check_vma`` along the way.
-* ``Mesh`` axis types — ``jax.sharding.AxisType`` (and the ``axis_types=``
-  kwarg of ``jax.make_mesh``) only exist on 0.5+; on 0.4.x every axis is
-  implicitly Auto, which is exactly what this repo wants.
-* Pallas TPU compiler params — ``pltpu.TPUCompilerParams`` was renamed
-  ``pltpu.CompilerParams``.
-
-Everything below is a thin, behavior-preserving wrapper: callers write the
-modern spelling once and run on whichever JAX the container bakes in.
-Collective wrappers (``all_to_all`` / ``psum_scatter``) are re-exported
-here too so distributed code has a single import surface to audit when the
-next migration lands.
+One audited import surface for the mesh, ``shard_map`` and collective
+calls, so the next JAX API migration lands in one file. Every function
+is a thin wrapper over the installed spelling.
 """
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
-    "JAX_VERSION",
     "shard_map",
     "make_mesh",
     "with_sharding_constraint",
     "all_to_all",
     "psum_scatter",
     "ppermute",
-    "tpu_compiler_params",
-    "cost_analysis",
 ]
-
-
-def _parse_version(v: str) -> Tuple[int, ...]:
-    parts = []
-    for tok in v.split(".")[:3]:
-        num = ""
-        for ch in tok:
-            if ch.isdigit():
-                num += ch
-            else:
-                break
-        parts.append(int(num or 0))
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _parse_version(jax.__version__)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map  # promoted out of experimental in 0.5.3
-else:  # 0.4.x / early 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-# The check kwarg rename (check_rep → check_vma) did NOT land with the
-# promotion — 0.5.3–0.6.0 expose jax.shard_map that still takes
-# check_rep — so detect by signature, not by module location.
-try:
-    _CHECK_KWARG = ("check_vma" if "check_vma" in
-                    inspect.signature(_shard_map_impl).parameters
-                    else "check_rep")
-except (TypeError, ValueError):  # signature unavailable: assume modern
-    _CHECK_KWARG = "check_vma"
 
 
 def shard_map(f: Callable, *, mesh: Mesh, in_specs, out_specs,
               check: bool = False) -> Callable:
-    """Portable ``jax.shard_map``.
+    """``jax.shard_map`` with ``check`` as the ``check_vma`` flag.
 
-    ``check`` maps to ``check_vma`` (0.6+) or ``check_rep`` (≤0.5) — the
-    replication/varying-manual-axes validation pass. The repo always runs
-    with it off: the SHIRO bodies use collectives whose replication rules
-    the old checker rejects spuriously.
+    The repo always runs with it off: the SHIRO bodies use collectives
+    whose varying-manual-axes rules the checker rejects spuriously.
     """
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KWARG: check})
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices: Optional[Sequence[Any]] = None) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    On jax ≥0.5 the explicit ``AxisType.Auto`` silences the 0.9 implicit-
-    axis-type warning; on 0.4.x the kwarg (and enum) don't exist and every
-    axis is Auto already, so a plain ``jax.make_mesh`` is equivalent.
-    """
+    """``jax.make_mesh`` with every axis explicitly ``AxisType.Auto``."""
     kwargs: dict = {}
     if devices is not None:
         kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes),
-                                 axis_types=(axis_type.Auto,) * len(axes),
-                                 **kwargs)
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), **kwargs)
 
 
 def with_sharding_constraint(x, sharding):
@@ -149,35 +75,3 @@ def ppermute(x: jax.Array, axis_name: str,
     the schedules rely on.
     """
     return jax.lax.ppermute(x, axis_name, perm)
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``.
-
-    jax ≤0.4.x returns a one-element LIST of per-program dicts; 0.5+
-    returns the dict directly. Always returns a dict ({} when XLA
-    provides nothing).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=None)
-def _pltpu():
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (0.6+) / ``pltpu.TPUCompilerParams`` (≤0.5)."""
-    pltpu = _pltpu()
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)

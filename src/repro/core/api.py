@@ -68,7 +68,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..distributed.topology import Topology
-from ..launch.hlo_analysis import executable_memory
+from ..launch.hlo_analysis import executable_memory, strip_metadata
 from ..robustness import faults, guards
 from .comm_model import (
     NetworkSpec, choose_fused_schedule, choose_hier_fused_schedule,
@@ -665,9 +665,11 @@ class DistSpmm:
         b_in = b
         b = self._put(b)
         fn = self._executable(b.shape[1], b.dtype, name)
-        if self._donate and b is b_in:
-            # the caller handed us an already-placed device array; donating
-            # it would consume THEIR buffer — donate a private copy instead
+        if self._donate and isinstance(b_in, jax.Array):
+            # the caller handed us a device array; placing it can hand back
+            # THEIR buffer under a new Array (same device, an equivalent
+            # sharding), and donating that would delete it — donate a
+            # private copy instead
             b = b.copy()
         c = fn(self._device_ex(), b)
         self.calls += 1
@@ -825,19 +827,22 @@ class DistSpmm:
         ``kernel=`` selects the family (default: the config's);
         ``n_feat`` is the F width of the dense X/Y operands for
         sddmm/fused, ``n_cols`` the B width for spmm/fused — both
-        default to ``config.n_dense_hint``.
+        default to ``config.n_dense_hint``. Source metadata is stripped
+        (``hlo_analysis.strip_metadata``), so identical programs give
+        identical text whatever call site lowered them.
         """
         kern, edge_name = self._resolve_call(kernel, edge)
         n = int(n_cols if n_cols is not None else self.config.n_dense_hint)
         f = int(n_feat if n_feat is not None else self.config.n_dense_hint)
         name = self._backend_name(backend)
         if kern == "sddmm":
-            return self._sddmm_executable(f, dtype, dtype, name,
-                                          edge_name).as_text()
-        if kern == "fused":
-            return self._fused_executable(f, n, dtype, dtype, dtype, name,
-                                          edge_name).as_text()
-        return self._executable(n, dtype, name).as_text()
+            compiled = self._sddmm_executable(f, dtype, dtype, name, edge_name)
+        elif kern == "fused":
+            compiled = self._fused_executable(f, n, dtype, dtype, dtype, name,
+                                              edge_name)
+        else:
+            compiled = self._executable(n, dtype, name)
+        return strip_metadata(compiled.as_text())
 
     # ----- introspection ----------------------------------------------
 

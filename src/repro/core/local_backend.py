@@ -286,6 +286,12 @@ class BsrBackend:
     interpret: Union[bool, None] = None
     impl: str = "pallas"
 
+    def resolve_interpret(self) -> bool:
+        """``interpret`` with None resolved: interpret mode off a TPU."""
+        if self.interpret is None:
+            return jax.default_backend() != "tpu"
+        return bool(self.interpret)
+
     def prepare(self, csrs: List[CSRMatrix]) -> Piece:
         per = [ell_from_csr(c, self.block) for c in csrs]
         t = max(bc.shape[1] for bc, _ in per)
@@ -315,11 +321,8 @@ class BsrBackend:
 
             n_pad = _round_up(n, self.bn)
             b_p = jnp.pad(b, ((0, kb * bk - k), (0, n_pad - n)))
-            interpret = self.interpret
-            if interpret is None:
-                interpret = jax.default_backend() != "tpu"
             out = bsr_spmm_pallas(cols, blocks, b_p, bn=self.bn,
-                                  interpret=bool(interpret))
+                                  interpret=self.resolve_interpret())
         return out[:m_out, :n].astype(b.dtype)
 
     def prepare_segments(self, csrs: List[CSRMatrix],
@@ -374,11 +377,8 @@ class BsrBackend:
         m_out = acc.shape[0]
         acc_p = jnp.pad(acc.astype(jnp.float32),
                         ((0, mb * bm - m_out), (0, n_pad - n)))
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         out = bsr_spmm_acc_pallas(cols, blocks, b_p, acc_p, bn=self.bn,
-                                  interpret=bool(interpret))
+                                  interpret=self.resolve_interpret())
         return out[:m_out, :n].astype(b_prefix.dtype)
 
     def sddmm(self, piece: Piece, x: jax.Array, y: jax.Array) -> jax.Array:
@@ -399,11 +399,8 @@ class BsrBackend:
         x3 = x3.reshape(mb, bm, f_pad)
         y3 = jnp.pad(y, ((0, kb * bk - y.shape[0]), (0, f_pad - f)))
         y3 = y3.reshape(kb, bk, f_pad)
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return bsr_sddmm_op(cols, blocks, x3, y3, impl=self.impl,
-                            interpret=bool(interpret)).astype(x.dtype)
+                            interpret=self.resolve_interpret()).astype(x.dtype)
 
     def with_values(self, piece: Piece, vals: jax.Array) -> Piece:
         return dict(piece, blocks=vals)

@@ -365,6 +365,68 @@ def power_law_sparse(m: int, k: int, nnz: int, alpha: float = 1.5, seed: int = 0
     return csr_from_coo(coo_from_arrays((m, k), row, col, val))
 
 
+def power_law_graph(n: int, nnz: int, alpha: float = 0.7, seed: int = 0,
+                    max_rounds: int = 64) -> CSRMatrix:
+    """Directed ``n``-node graph with exactly ``nnz`` distinct edges.
+
+    Both endpoints of each edge are drawn with Zipf weights
+    ``rank ** -alpha`` over node ids shuffled from ``seed``, so hubs are
+    spread over the id range instead of packed at its start. Unlike
+    ``power_law_sparse``, duplicate draws do not shrink the graph: it
+    draws again until ``nnz`` distinct edges exist, then keeps a seeded
+    random ``nnz`` of them. ``alpha=0`` gives a uniform graph. Self
+    loops are left out (``models.gnn.normalize_adjacency`` adds them);
+    every value is 1.
+    """
+    if not 0 < nnz <= n * (n - 1):
+        raise ValueError(f"nnz={nnz} must be in (0, n*(n-1)] for n={n}")
+    rng = np.random.default_rng(seed)
+    w = np.arange(1, n + 1, dtype=np.float64) ** (-alpha)
+    w /= w.sum()
+    row_ids, col_ids = rng.permutation(n), rng.permutation(n)
+    keys = np.empty(0, np.int64)
+    for _ in range(max_rounds):
+        short = nnz - keys.size
+        if short <= 0:
+            break
+        draw = short + short // 4 + 1024
+        r = row_ids[rng.choice(n, size=draw, p=w)].astype(np.int64)
+        c = col_ids[rng.choice(n, size=draw, p=w)].astype(np.int64)
+        keys = np.union1d(keys, (r * n + c)[r != c])
+    else:
+        raise ValueError(
+            f"{keys.size} distinct edges after {max_rounds} rounds, short "
+            f"of nnz={nnz}: alpha={alpha} concentrates too many draws on "
+            f"the same pairs")
+    keys = np.sort(rng.choice(keys, size=nnz, replace=False))
+    row, col = keys // n, keys % n
+    return csr_from_coo(coo_from_arrays((n, n), row, col,
+                                        np.ones(nnz, np.float32)))
+
+
+def ell_bytes(a: CSRMatrix, block_shape: Tuple[int, int],
+              tile: Tuple[int, int] = (8, 128)) -> int:
+    """Bytes of ``ell_from_csr(a, block_shape)``, reckoned without building it.
+
+    Every block row is padded to the densest one, so a power-law row
+    pattern can need far more than its nonzeros. ``tile`` rounds the two
+    minor dims of each f32 block up as the device lays them out: a TPU
+    keeps the last two dims of an f32 array in (8, 128) tiles, so an
+    (8, 8) block takes 16 times its size there. ``tile=(1, 1)`` gives the
+    dense bytes.
+    """
+    bm, bk = block_shape
+    m, k = a.shape
+    mb, kb = (m + bm - 1) // bm, (k + bk - 1) // bk
+    coo = a.to_coo()
+    key = np.unique(coo.row.astype(np.int64) // bm * kb
+                    + coo.col.astype(np.int64) // bk)
+    t = max(1, int(np.bincount(key // kb, minlength=mb).max(initial=0)))
+    tm, tk = tile
+    block = -(-bm // tm) * tm * (-(-bk // tk) * tk) * 4
+    return mb * t * (block + 4)
+
+
 def hub_sparse(m: int, k: int, n_hub_rows: int, n_hub_cols: int, fill: float, seed: int = 0) -> CSRMatrix:
     """Hub-structured matrix (mawi-like traffic pattern: few hubs touch all).
 

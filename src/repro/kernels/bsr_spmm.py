@@ -15,6 +15,11 @@ across the innermost t axis and accumulated in VMEM (init at t == 0).
 VMEM working set per step: bm·bk (A block) + bk·bn (B tile) + bm·bn (C
 tile); with the default 128³ tiles that is 3·64 KiB of fp32 — comfortably
 inside the ~16 MiB VMEM budget, leaving room for double buffering.
+
+``block_cols`` is prefetched into SMEM flattened, in chunks of block rows
+(``kernels.prefetch``): one ``pallas_call`` per chunk, each reading the
+whole ``blocks`` / ``B`` operands through offset index maps and writing
+its own rows of C.
 """
 from __future__ import annotations
 
@@ -25,9 +30,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from .prefetch import chunks
 
-__all__ = ["bsr_spmm_pallas", "bsr_spmm_acc_pallas"]
+__all__ = ["bsr_spmm_pallas", "bsr_spmm_acc_pallas", "block_dot"]
+
+
+def block_dot(a: jax.Array, b: jax.Array, contract) -> jax.Array:
+    """f32-accumulated dot of two blocks inside a kernel.
+
+    Mosaic runs a dot of f32 operands as one bf16 pass unless asked for
+    ``HIGHEST``; on a TPU v5e that left the bsr SpMM's C up to 1.5e-2 off
+    an f32 reference. f32 operands therefore get ``HIGHEST``; bf16 ones
+    keep the single pass.
+    """
+    f32 = jnp.float32 in (a.dtype, b.dtype)
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
+    )
 
 
 def _kernel(cols_ref, blocks_ref, b_ref, out_ref, *, t_steps: int):
@@ -43,10 +64,48 @@ def _kernel(cols_ref, blocks_ref, b_ref, out_ref, *, t_steps: int):
     # clamped index map only changes WHICH (ignored) B tile is prefetched.
     # The out tile is an f32 accumulator (MXU-native): bf16 inputs,
     # f32 partials — matches the ref.py oracle's accumulation order.
-    out_ref[...] += jax.lax.dot_general(
-        a_blk, b_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    out_ref[...] += block_dot(a_blk, b_blk, ((1,), (0,)))
+
+
+def _bsr_call(kernel, block_cols, blocks, b3, acc, lo, hi, bn, interpret):
+    """One chunk of block rows ``[lo, hi)``; ``acc`` (or None) is its C seed."""
+    _, t_steps, bm, bk = blocks.shape
+    n = b3.shape[2]
+    cols = block_cols[lo:hi].reshape(-1)
+    in_specs = [
+        pl.BlockSpec((1, 1, bm, bk), lambda i, j, t, cols: (i + lo, t, 0, 0)),
+        pl.BlockSpec(
+            (1, bk, bn),
+            lambda i, j, t, cols: (jnp.maximum(cols[i * t_steps + t], 0), 0, j),
+        ),
+    ]
+    operands = [cols, blocks, b3]
+    aliases = {}
+    if acc is not None:
+        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, t, cols: (i, j)))
+        operands.append(acc)
+        # operand index counts the scalar-prefetch arg: acc is input 3
+        aliases = {3: 0}
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(hi - lo, n // bn, t_steps),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t, cols: (i, j)),
     )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(((hi - lo) * bm, n), jnp.float32),
+        input_output_aliases=aliases,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+    )(*operands)
+
+
+def _concat(outs):
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -63,30 +122,10 @@ def bsr_spmm_pallas(
     n = b.shape[1]
     if n % bn:
         raise ValueError(f"n={n} must be a multiple of bn={bn}")
-    n_tiles = n // bn
     b3 = b.reshape(-1, bk, n)  # block-row view [kb, bk, n]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(mb, n_tiles, t_steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bk), lambda i, j, t, cols: (i, t, 0, 0)),
-            pl.BlockSpec(
-                (1, bk, bn),
-                lambda i, j, t, cols: (jnp.maximum(cols[i, t], 0), 0, j),
-            ),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t, cols: (i, j)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, t_steps=t_steps),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mb * bm, n), jnp.float32),
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(block_cols, blocks, b3)
+    kernel = functools.partial(_kernel, t_steps=t_steps)
+    out = _concat([_bsr_call(kernel, block_cols, blocks, b3, None, lo, hi, bn, interpret)
+                   for lo, hi in chunks(mb, t_steps)])
     return out.astype(b.dtype)
 
 
@@ -99,10 +138,7 @@ def _acc_kernel(cols_ref, blocks_ref, b_ref, acc_ref, out_ref):
 
     a_blk = blocks_ref[0, 0]  # [bm, bk]
     b_blk = b_ref[0]  # [bk, bn]
-    out_ref[...] += jax.lax.dot_general(
-        a_blk, b_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += block_dot(a_blk, b_blk, ((1,), (0,)))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"),
@@ -133,31 +169,9 @@ def bsr_spmm_acc_pallas(
         raise ValueError(f"n={n} must be a multiple of bn={bn}")
     if acc.shape != (mb * bm, n):
         raise ValueError(f"acc shape {acc.shape} != {(mb * bm, n)}")
-    n_tiles = n // bn
     b3 = b.reshape(-1, bk, n)  # block-row view [kb, bk, n]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(mb, n_tiles, t_steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bk), lambda i, j, t, cols: (i, t, 0, 0)),
-            pl.BlockSpec(
-                (1, bk, bn),
-                lambda i, j, t, cols: (jnp.maximum(cols[i, t], 0), 0, j),
-            ),
-            pl.BlockSpec((bm, bn), lambda i, j, t, cols: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t, cols: (i, j)),
-    )
-    out = pl.pallas_call(
-        _acc_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mb * bm, n), jnp.float32),
-        # operand index counts the scalar-prefetch arg: acc is input 3
-        input_output_aliases={3: 0},
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-    )(block_cols, blocks, b3, acc.astype(jnp.float32))
+    acc = acc.astype(jnp.float32)
+    out = _concat([_bsr_call(_acc_kernel, block_cols, blocks, b3,
+                             acc[lo * bm:hi * bm], lo, hi, bn, interpret)
+                   for lo, hi in chunks(mb, t_steps)])
     return out.astype(b.dtype)

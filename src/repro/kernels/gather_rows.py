@@ -3,9 +3,17 @@
 This is SHIRO's communication stage-① hot spot: before any B-row transfer
 (flat column-based or hierarchical inter-group fetch) the selected rows are
 packed into a contiguous send buffer. On GPU this is a gather kernel; on
-TPU we tile rows in groups of ``bs`` and let a scalar-prefetched index map
-fetch one source row per grid step, so the gather overlaps the pipeline's
-tile copies (HBM→VMEM) instead of issuing random accesses from compute.
+TPU a scalar-prefetched index map fetches one source row per grid step,
+so the gather overlaps the pipeline's tile copies (HBM→VMEM) instead of
+issuing random accesses from compute.
+
+Rows are viewed as ``[rows, 1, n]``: the TPU compiler accepts a block
+only when its last two dims are (8k, 128k) multiples or equal the
+array's own, and a one-row block of a 2-D ``[rows, n]`` array is
+neither. In the 3-D view the block ``(1, bn)`` spans the full second-
+minor dim, and XLA lays such arrays out in (1, 128) tiles, so the view
+costs a relayout copy, not padding to 8 sublanes. ``idx`` is prefetched
+into SMEM in chunks of slots (``kernels.prefetch``).
 
 Padding: idx < 0 → output row zeroed (the send slot is a plan pad).
 """
@@ -18,16 +26,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from .prefetch import chunks
 
 __all__ = ["gather_rows_pallas"]
 
 
 def _kernel(idx_ref, b_ref, out_ref):
     s = pl.program_id(0)
-    valid = idx_ref[s] >= 0
-    row = b_ref[0]  # [bn] tile of the prefetched source row
-    out_ref[0, :] = jnp.where(valid, row, jnp.zeros_like(row))
+    row = b_ref[...]  # [1, bn] tile of the prefetched source row
+    out_ref[...] = jnp.where(idx_ref[s] >= 0, row, jnp.zeros_like(row))
+
+
+def _gather(b3: jax.Array, idx: jax.Array, bn: int, interpret: bool) -> jax.Array:
+    s_total = idx.shape[0]
+    n = b3.shape[2]
+    row = (pl.Squeezed(), 1, bn)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(s_total, n // bn),
+        in_specs=[
+            pl.BlockSpec(row, lambda s, j, idx: (jnp.maximum(idx[s], 0), 0, j)),
+        ],
+        out_specs=pl.BlockSpec(row, lambda s, j, idx: (s, 0, j)),
+    )
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_total, 1, n), b3.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel"),
+        ),
+    )(idx, b3)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -40,25 +70,10 @@ def gather_rows_pallas(
 ) -> jax.Array:
     """Returns out [S, n] with out[s] = b[idx[s]] (zeros where idx < 0)."""
     s_total = idx.shape[0]
-    n = b.shape[1]
+    k, n = b.shape
     if n % bn:
         bn = n  # fall back to full-row tiles for narrow matrices
-    n_tiles = n // bn
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s_total, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, bn), lambda s, j, idx: (jnp.maximum(idx[s], 0), j)),
-        ],
-        out_specs=pl.BlockSpec((1, bn), lambda s, j, idx: (s, j)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_total, n), b.dtype),
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "parallel"),
-        ),
-    )(idx, b)
+    b3 = b.reshape(k, 1, n)
+    outs = [_gather(b3, idx[lo:hi], bn, interpret) for lo, hi in chunks(s_total)]
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    return out.reshape(s_total, n)

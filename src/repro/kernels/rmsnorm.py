@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["rmsnorm_pallas"]
 
@@ -52,7 +51,7 @@ def rmsnorm_pallas(x: jax.Array, gain: jax.Array, *, eps: float = 1e-5,
         out_specs=pl.BlockSpec((brr, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(x2, gain)
     return out.reshape(shape)

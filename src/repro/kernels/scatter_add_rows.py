@@ -14,6 +14,13 @@ The C argument is donated and aliased to the output, so untouched rows
 keep their values without any copy. ``tgt`` must be sorted ascending with
 -1 (dropped pads) sorted to the END and clamped to row 0 contributing
 zeros — ``prepare_sorted_scatter`` below does this host-side.
+
+Both row operands are viewed as ``[rows, 1, n]`` so that each one-row
+block spans the array's full second-minor dim, which the TPU compiler
+requires of blocks that are not (8, 128)-aligned (see ``gather_rows``).
+``meta`` is prefetched into SMEM in chunks of slots (``kernels.prefetch``);
+each chunk's call takes the previous one's output as its aliased C, so a
+segment cut by a chunk boundary resumes from its partial sum.
 """
 from __future__ import annotations
 
@@ -25,7 +32,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from .prefetch import chunks
 
 __all__ = ["scatter_add_rows_sorted_pallas", "prepare_sorted_scatter"]
 
@@ -56,15 +63,42 @@ def _kernel(meta_ref, part_ref, c_ref, out_ref, *, s_total: int):
     t = meta_ref[s]
     prev = meta_ref[jnp.maximum(s - 1, 0)]
     new_segment = jnp.logical_or(s == 0, t != prev)
-    contrib = jnp.where(s < n_valid, part_ref[0], jnp.zeros_like(part_ref[0]))
+    part = part_ref[...]
+    contrib = jnp.where(s < n_valid, part, jnp.zeros_like(part))
 
     @pl.when(new_segment)
     def _init():
-        out_ref[0, :] = c_ref[0] + contrib
+        out_ref[...] = c_ref[...] + contrib
 
     @pl.when(jnp.logical_not(new_segment))
     def _acc():
-        out_ref[0, :] += contrib
+        out_ref[...] += contrib
+
+
+def _scatter(c3: jax.Array, parts3: jax.Array, meta: jax.Array, lo: int,
+             interpret: bool) -> jax.Array:
+    s_total = meta.shape[0] - 1
+    m, _, n = c3.shape
+    row = (pl.Squeezed(), 1, n)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(s_total,),
+        in_specs=[
+            pl.BlockSpec(row, lambda s, meta: (s + lo, 0, 0)),
+            pl.BlockSpec(row, lambda s, meta: (meta[s], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec(row, lambda s, meta: (meta[s], 0, 0)),
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, s_total=s_total),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, 1, n), c3.dtype),
+        interpret=interpret,
+        input_output_aliases={2: 0},  # alias C (arg index counts scalar first)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+    )(meta, parts3, c3)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -76,24 +110,12 @@ def scatter_add_rows_sorted_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     s_total = partials_sorted.shape[0]
-    n = c.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s_total,),
-        in_specs=[
-            pl.BlockSpec((1, n), lambda s, meta: (s, 0)),
-            pl.BlockSpec((1, n), lambda s, meta: (meta[s], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n), lambda s, meta: (meta[s], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, s_total=s_total),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
-        interpret=interpret,
-        input_output_aliases={2: 0},  # alias C (arg index counts scalar first)
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
-        ),
-    )(meta, partials_sorted, c)
+    m, n = c.shape
+    parts3 = partials_sorted.reshape(s_total, 1, n)
+    out = c.reshape(m, 1, n)
+    n_valid = meta[s_total]
+    for lo, hi in chunks(s_total):
+        valid = jnp.clip(n_valid - lo, 0, hi - lo).astype(meta.dtype)
+        out = _scatter(out, parts3, jnp.concatenate([meta[lo:hi], valid[None]]),
+                       lo, interpret)
+    return out.reshape(m, n)

@@ -16,6 +16,8 @@ prefetched index map reading ``block_cols[i, t]`` (clamped; the clamp
 only changes WHICH ignored tile is prefetched for padding slots). VMEM
 working set per step: bm·f (X tile) + bk·f (Y tile) + 2·bm·bk (A block +
 out block) — at 128-wide f that is well inside the VMEM budget.
+``block_cols`` is prefetched into SMEM flattened, in chunks of block rows
+(``kernels.prefetch``), as in ``kernels.bsr_spmm``.
 
 ``bsr_sddmm_ref`` is the pure-jnp oracle (single source of correctness
 truth, as for every kernel in this package) and ``bsr_sddmm_op`` the
@@ -33,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
+from .bsr_spmm import block_dot
+from .prefetch import chunks
 
 __all__ = ["bsr_sddmm_ref", "bsr_sddmm_pallas", "bsr_sddmm_op"]
 
@@ -62,10 +65,36 @@ def _kernel(cols_ref, blocks_ref, x_ref, y_ref, out_ref):
     # sample the outer product at this block position; padding slots have
     # all-zero A blocks so the (arbitrary) prefetched Y tile is silenced
     # by the multiply — same no-masking property as the SpMM kernel
-    out_ref[0, 0] = a_blk * jax.lax.dot_general(
-        x_blk, y_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    out_ref[0, 0] = a_blk * block_dot(x_blk, y_blk, ((1,), (1,)))
+
+
+def _sddmm_call(block_cols, blocks, x3, y3, lo, hi, interpret):
+    """One chunk of block rows ``[lo, hi)`` of the sampled values."""
+    _, t_steps, bm, bk = blocks.shape
+    f = x3.shape[2]
+    cols = block_cols[lo:hi].reshape(-1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(hi - lo, t_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, bm, bk), lambda i, t, cols: (i + lo, t, 0, 0)),
+            pl.BlockSpec((1, bm, f), lambda i, t, cols: (i + lo, 0, 0)),
+            pl.BlockSpec(
+                (1, bk, f),
+                lambda i, t, cols: (jnp.maximum(cols[i * t_steps + t], 0), 0, 0),
+            ),
+        ],
+        out_specs=pl.BlockSpec((1, 1, bm, bk), lambda i, t, cols: (i, t, 0, 0)),
     )
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((hi - lo, t_steps, bm, bk), jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+    )(cols, blocks, x3, y3)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -83,33 +112,12 @@ def bsr_sddmm_pallas(
     a lane multiple (128) for MXU efficiency on real hardware.
     """
     mb, t_steps, bm, bk = blocks.shape
-    f = x3.shape[2]
     if t_steps == 0:  # empty piece: nothing stored, nothing sampled
         return jnp.zeros((mb, 0, bm, bk), jnp.float32)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(mb, t_steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, bk), lambda i, t, cols: (i, t, 0, 0)),
-            pl.BlockSpec((1, bm, f), lambda i, t, cols: (i, 0, 0)),
-            pl.BlockSpec(
-                (1, bk, f),
-                lambda i, t, cols: (jnp.maximum(cols[i, t], 0), 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bm, bk),
-                               lambda i, t, cols: (i, t, 0, 0)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mb, t_steps, bm, bk), jnp.float32),
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-    )(block_cols, blocks, x3, y3)
+    outs = [_sddmm_call(block_cols, blocks, x3, y3, lo, hi, interpret)
+            for lo, hi in chunks(mb, t_steps)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
 
 @functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5))

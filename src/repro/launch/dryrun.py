@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import JAX_VERSION, cost_analysis
 from ..configs import ARCHS, get_config
 from ..distributed.context import make_context
 from ..distributed.sharding import (
@@ -118,7 +117,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
         "mode": shape.mode,
         # records from different jax versions compile different HLO; tag
         # them so §Roofline comparisons never mix compiler generations
-        "jax": ".".join(map(str, JAX_VERSION)),
+        "jax": jax.__version__,
     }
     status = cell_status(cfg, shape)
     rec["status"] = status
@@ -275,7 +274,7 @@ def _compile_one(cfg, shape, mesh, dist, t0, chips) -> Dict[str, Any]:
     compiled = lowered.compile()
     rec["compile_s"] = round(time.time() - t1, 2)
 
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     rec["memory"] = _mem_dict(compiled.memory_analysis())
     rec["cost"] = {k: float(v) for k, v in cost.items()

@@ -19,7 +19,8 @@ import re
 from typing import Dict, Optional
 
 __all__ = ["DTYPE_BYTES", "parse_shape_bytes", "collective_bytes",
-           "collective_rows", "roofline", "executable_memory", "HW"]
+           "collective_rows", "roofline", "executable_memory",
+           "strip_metadata", "HW"]
 
 HW = {
     "peak_flops": 197e12,  # bf16 FLOP/s per chip
@@ -44,6 +45,29 @@ _COLLECTIVES = (
     "collective-permute-start", "ragged-all-to-all",
 )
 _DONE = ("all-gather-done", "all-reduce-done", "collective-permute-done")
+# XLA prints ``/*index=5*/`` markers inside long operand and type lists
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+_METADATA_RE = re.compile(r", metadata=\{[^{}]*\}")
+_FRAME_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def strip_metadata(hlo_text: str) -> str:
+    """HLO text without source metadata.
+
+    Drops every instruction's ``metadata={...}`` and the stack-frame
+    tables printed after the module header. Those carry the Python file,
+    line and column each op was traced from, so two identical programs
+    lowered from different call sites differ only there.
+    """
+    lines, in_table = [], False
+    for line in hlo_text.splitlines():
+        if line in _FRAME_TABLES:
+            in_table = True
+        elif in_table and not line.strip():
+            in_table = False
+        elif not in_table:
+            lines.append(_METADATA_RE.sub("", line))
+    return "\n".join(lines) + "\n"
 
 
 def parse_shape_bytes(type_str: str) -> int:
@@ -65,6 +89,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     sizes: Dict[str, int] = {}
     colls = []
     for line in hlo_text.splitlines():
+        line = _COMMENT_RE.sub("", line)
         m = _DEF_RE.match(line)
         if not m:
             continue

@@ -24,8 +24,7 @@ __all__ = ["make_production_mesh", "make_mesh", "make_spmm_mesh"]
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Version-portable jax.make_mesh (explicit Auto axis types on jax≥0.5,
-    graceful fallback to a plain mesh on 0.4.x — see repro.compat)."""
+    """``jax.make_mesh`` with explicit Auto axis types (see repro.compat)."""
     return _compat_make_mesh(shape, axes)
 
 

@@ -31,6 +31,12 @@ each owning a slice of the devices — the multi-controller SPMD model:
   the intrinsic two-tier structure), so ``hier="auto"`` /
   ``net="auto"`` read the real substrate.
 
+The fleet is CPU-only: each worker sets ``JAX_PLATFORMS=cpu`` unless the
+caller already set it, and fakes its local devices with
+``--xla_force_host_platform_device_count``. A chip belongs to one
+process, so on a TPU host one process drives every local chip instead
+(``Topology.local(P)``, as ``chip_smoke.py --chips 4`` does).
+
 Two entry modes:
 
   launcher (the default; what CI runs):

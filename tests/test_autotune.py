@@ -190,18 +190,22 @@ def test_donation_never_changes_c(power_law_matrix, overlap):
     np.testing.assert_array_equal(outs[0], outs[1])
 
 
-def test_donation_spares_caller_device_arrays(power_law_matrix):
-    """Donating must consume OUR copy, never the caller's array."""
+@pytest.mark.parametrize("placement", ["handle_sharding", "default_device"])
+def test_donation_spares_caller_device_arrays(power_law_matrix, placement):
+    """Donating must consume OUR copy, never the caller's array — also
+    when placing it hands back the caller's buffer under a new Array (a
+    default-device array fed to a one-device handle)."""
     import jax
     import jax.numpy as jnp
 
     a = power_law_matrix()
-    h = compile_spmm(a, P, SpmmConfig(backends=("coo",), schedule=2))
+    p = P if placement == "handle_sharding" else 1
+    h = compile_spmm(a, p, SpmmConfig(backends=("coo",), schedule=2))
     assert h._donate
-    b = jax.device_put(
-        jnp.asarray(np.random.default_rng(0)
-                    .standard_normal((a.shape[1], N)).astype(np.float32)),
-        h._in_sharding)
+    b = jnp.asarray(np.random.default_rng(0)
+                    .standard_normal((a.shape[1], N)).astype(np.float32))
+    if placement == "handle_sharding":
+        b = jax.device_put(b, h._in_sharding)
     c1 = np.asarray(h(b))
     c2 = np.asarray(h(b))  # would raise on a deleted/donated caller buffer
     np.testing.assert_array_equal(c1, c2)

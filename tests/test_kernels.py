@@ -2,11 +2,13 @@
 
 All kernels run in interpret mode (CPU container; TPU is the target).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.bsr_spmm import bsr_spmm_pallas
+from repro.kernels import prefetch
+from repro.kernels.bsr_spmm import bsr_spmm_acc_pallas, bsr_spmm_pallas
 from repro.kernels.gather_rows import gather_rows_pallas
 from repro.kernels.ops import (
     gather_rows_op, prepare_sorted_scatter, scatter_add_rows_op,
@@ -15,6 +17,7 @@ from repro.kernels.ref import (
     bsr_spmm_ref, gather_rows_ref, scatter_add_rows_ref,
 )
 from repro.kernels.scatter_add_rows import scatter_add_rows_sorted_pallas
+from repro.kernels.sddmm import bsr_sddmm_pallas
 
 
 BSR_SHAPES = [
@@ -104,3 +107,50 @@ def test_ops_dispatch_interpret_backend(monkeypatch):
     out = scatter_add_rows_op(c, parts, tgt)
     ref = scatter_add_rows_ref(c, parts, jnp.asarray(tgt))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+def _chunk_cases():
+    """One call per kernel on operands whose index tables span several
+    SMEM chunks once ``prefetch.TABLE_ENTRIES`` is patched down to 7."""
+    rng = np.random.default_rng(3)
+    mb, t, bm, bk, n = 5, 4, 8, 8, 16
+    cols = rng.integers(-1, mb, size=(mb, t)).astype(np.int32)
+    blocks = rng.standard_normal((mb, t, bm, bk)).astype(np.float32)
+    blocks[cols < 0] = 0.0
+    b = rng.standard_normal((mb * bk, n)).astype(np.float32)
+    acc = rng.standard_normal((mb * bm, n)).astype(np.float32)
+    x3 = rng.standard_normal((mb, bm, n)).astype(np.float32)
+    idx = rng.integers(-1, mb * bk, 23).astype(np.int32)
+    tgt = rng.integers(-1, mb * bm, 23).astype(np.int32)
+    parts = rng.standard_normal((23, n)).astype(np.float32)
+    perm, meta = prepare_sorted_scatter(tgt)
+    cols, blocks, b, acc, x3, idx, parts, meta = map(
+        jnp.asarray, (cols, blocks, b, acc, x3, idx, parts[perm], meta))
+    return {
+        "gather_rows": lambda: gather_rows_pallas(b, idx, interpret=True),
+        "scatter_add_rows": lambda: scatter_add_rows_sorted_pallas(
+            acc, parts, meta, interpret=True),
+        "bsr_spmm": lambda: bsr_spmm_pallas(cols, blocks, b, bn=n,
+                                            interpret=True),
+        "bsr_spmm_acc": lambda: bsr_spmm_acc_pallas(cols, blocks, b, acc + 0,
+                                                    bn=n, interpret=True),
+        "bsr_sddmm": lambda: bsr_sddmm_pallas(cols, blocks, x3, x3,
+                                              interpret=True),
+    }
+
+
+@pytest.mark.parametrize("kernel", sorted(_chunk_cases()))
+def test_smem_chunking_is_bit_identical(kernel, monkeypatch):
+    """Index tables split into SMEM-sized chunks (one pallas_call each)
+    give the same bits as one call: segments and accumulation chains cut
+    by a chunk boundary resume where they stopped."""
+    call = _chunk_cases()[kernel]
+    jax.clear_caches()
+    whole = np.asarray(call())
+    monkeypatch.setattr(prefetch, "TABLE_ENTRIES", 7)
+    jax.clear_caches()  # the kernels read the budget while tracing
+    try:
+        chunked = np.asarray(call())
+    finally:
+        jax.clear_caches()
+    np.testing.assert_array_equal(chunked, whole)
