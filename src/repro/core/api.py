@@ -96,6 +96,7 @@ from .hierarchy import HierPlan, build_hier_plan
 from .local_backend import get_backend
 from .planner import SpmmPlan, Strategy, build_plan, replicate_plan
 from .sparse import CSRMatrix, PatternSnapshot
+from .trace import span
 
 __all__ = [
     "SpmmConfig",
@@ -412,6 +413,7 @@ class DistSpmm:
         self._check = guards.check_mode(config)
         self.calls = 0             # concrete __call__ executions served
         self.numerical_faults = 0  # C sweeps that raised NumericalFault
+        self.guard_host_bytes = 0  # bytes the output sweeps copied to the host
         # replicated (1.5D) rungs route by schedule kind: the plan slot
         # holds the s-shard base plan and the exec plan leads [c, s, ...]
         self.replicated = getattr(schedule, "kind", "") == "replicated"
@@ -656,36 +658,49 @@ class DistSpmm:
         return self._call_spmm(operands[0], name)
 
     def _call_spmm(self, b, name: str) -> jax.Array:
-        if self._check:
-            guards.validate_dense_operand(
-                b, k_expected=self.plan.shape[1],
-                context=f"DistSpmm(P={self.plan.P}) call")
         if _is_tracer(b):
+            self._validate_b(b)
             return self._raw_call(b, name)
-        b_in = b
-        b = self._put(b)
-        fn = self._executable(b.shape[1], b.dtype, name)
-        if self._donate and isinstance(b_in, jax.Array):
-            # the caller handed us a device array; placing it can hand back
-            # THEIR buffer under a new Array (same device, an equivalent
-            # sharding), and donating that would delete it — donate a
-            # private copy instead
-            b = b.copy()
-        c = fn(self._device_ex(), b)
+        # host spans (core.trace): dispatch, then with check on the wait
+        # for C and the guard's sweep
+        with span("shiro.dispatch"):
+            self._validate_b(b)
+            b_in = b
+            b = self._put(b)
+            fn = self._executable(b.shape[1], b.dtype, name)
+            if self._donate and isinstance(b_in, jax.Array):
+                # the caller handed us a device array; placing it can hand
+                # back THEIR buffer under a new Array (same device, an
+                # equivalent sharding), and donating that would delete it
+                # — donate a private copy instead
+                b = b.copy()
+            c = fn(self._device_ex(), b)
         self.calls += 1
         # chaos hook: nan_poison at site "output" models a broken
         # backend kernel — fires with or without check, exactly like the
         # real failure it stands in for
         c = faults.maybe_poison_array(c, site="output")
         if self._check:
-            try:
-                guards.sampled_finite_check(
-                    c, mode=self._check, call_index=self.calls,
-                    context=f"DistSpmm(P={self.plan.P}) backend={name!r}")
-            except guards.NumericalFault:
-                self.numerical_faults += 1
-                raise
+            with span("shiro.wait"):
+                guards.start_host_copy(c)
+                jax.block_until_ready(c)
+            with span("shiro.guard") as guard:
+                try:
+                    swept = guards.sampled_finite_check(
+                        c, mode=self._check, call_index=self.calls,
+                        context=f"DistSpmm(P={self.plan.P}) backend={name!r}")
+                except guards.NumericalFault:
+                    self.numerical_faults += 1
+                    raise
+                self.guard_host_bytes += swept
+                guard.set_metadata(host_bytes=swept)
         return c
+
+    def _validate_b(self, b) -> None:
+        if self._check:
+            guards.validate_dense_operand(
+                b, k_expected=self.plan.shape[1],
+                context=f"DistSpmm(P={self.plan.P}) call")
 
     def _call_sddmm(self, x, y, *, name: str, edge: Optional[str]):
         if self._check:
@@ -703,7 +718,7 @@ class DistSpmm:
             lambda v: faults.maybe_poison_array(v, site="output"), vals)
         if self._check:
             try:
-                guards.sampled_finite_check_tree(
+                self.guard_host_bytes += guards.sampled_finite_check_tree(
                     vals, mode=self._check, call_index=self.calls,
                     context=f"DistSpmm(P={self.plan.P}) sddmm "
                             f"backend={name!r}")
@@ -731,7 +746,7 @@ class DistSpmm:
         c = faults.maybe_poison_array(c, site="output")
         if self._check:
             try:
-                guards.sampled_finite_check(
+                self.guard_host_bytes += guards.sampled_finite_check(
                     c, mode=self._check, call_index=self.calls,
                     context=f"DistSpmm(P={self.plan.P}) fused "
                             f"backend={name!r}")
@@ -889,6 +904,7 @@ class DistSpmm:
             values_refreshes=self.values_refreshes,
             check=self._check,
             calls=self.calls,
+            guard_host_bytes=self.guard_host_bytes,
             numerical_faults=self.numerical_faults,
         )
         out.setdefault("decision_source", "model")

@@ -51,6 +51,11 @@ collective-permute operands are identical to the staged schedule's, and
 C is bit-identical (the per-round accumulation replays the staged
 per-element addition chains exactly — see core.local_backend's
 cumulative-prefix contract).
+
+Every executor runs under ``jax.named_scope("shiro.spmm")``, so that a
+profile attributes its device ops, forward and backward, through the
+HLO's ``op_name`` metadata. The scope is metadata only: the compiled
+program is unchanged.
 """
 from __future__ import annotations
 
@@ -92,6 +97,9 @@ __all__ = [
 ]
 
 BackendSpec = Union[str, LocalSpmmBackend]
+
+# the named scope around every executor (see the module docstring)
+SCOPE = "shiro.spmm"
 
 # piece name -> backend-native arrays, all with leading [P, ...] (flat) or
 # [G, L, ...] (hier) axes so they shard over the mesh like any other leaf
@@ -701,8 +709,9 @@ def flat_spmm(plan: FlatExecPlan, b_global: jax.Array, mesh: Mesh,
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(axis),) * 7,
                    out_specs=P(axis))
-    return fn(pieces, plan.b_send_idx, plan.c_recv_rows,
-              plan.agg_perm, plan.agg_meta, plan.seg_agg, b_global)
+    with jax.named_scope(SCOPE):
+        return fn(pieces, plan.b_send_idx, plan.c_recv_rows,
+                  plan.agg_perm, plan.agg_meta, plan.seg_agg, b_global)
 
 
 # ---------------------------------------------------------------------------
@@ -907,9 +916,10 @@ def hier_spmm(plan: HierExecPlan, b_global: jax.Array, mesh: Mesh,
     fn = shard_map(body, mesh=mesh,
                    in_specs=(gl,) * 6 + (P((group_axis, local_axis)),),
                    out_specs=gl)
-    out = fn(pieces, plan.b_group_send_idx, plan.c_recv_rows,
-             plan.agg_perm, plan.agg_meta, plan.seg_agg, b_global)
-    return out.reshape(-1, b_global.shape[1])
+    with jax.named_scope(SCOPE):
+        out = fn(pieces, plan.b_group_send_idx, plan.c_recv_rows,
+                 plan.agg_perm, plan.agg_meta, plan.seg_agg, b_global)
+        return out.reshape(-1, b_global.shape[1])
 
 
 # ---------------------------------------------------------------------------
@@ -1000,5 +1010,6 @@ def replicated_spmm(plan: ReplicatedExecPlan, b_global: jax.Array,
     fn = shard_map(body, mesh=mesh,
                    in_specs=(rx,) * 6 + (P(axis),),
                    out_specs=P((axis, replica_axis)))
-    return fn(pieces, plan.b_send_idx, plan.c_recv_rows,
-              plan.agg_perm, plan.agg_meta, plan.seg_agg, b_global)
+    with jax.named_scope(SCOPE):
+        return fn(pieces, plan.b_send_idx, plan.c_recv_rows,
+                  plan.agg_perm, plan.agg_meta, plan.seg_agg, b_global)

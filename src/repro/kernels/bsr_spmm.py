@@ -94,6 +94,7 @@ def _bsr_call(kernel, block_cols, blocks, b3, acc, lo, hi, bn, interpret):
     )
     return pl.pallas_call(
         kernel,
+        name="bsr_spmm" if acc is None else "bsr_spmm_acc",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(((hi - lo) * bm, n), jnp.float32),
         input_output_aliases=aliases,

@@ -51,6 +51,7 @@ def _gather(b3: jax.Array, idx: jax.Array, bn: int, interpret: bool) -> jax.Arra
     )
     return pl.pallas_call(
         _kernel,
+        name="gather_rows",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_total, 1, n), b3.dtype),
         interpret=interpret,

@@ -43,6 +43,7 @@ def rmsnorm_pallas(x: jax.Array, gain: jax.Array, *, eps: float = 1e-5,
         brr = rows  # odd smoke shapes: single tile
     out = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
+        name="rmsnorm",
         grid=(rows // brr,),
         in_specs=[
             pl.BlockSpec((brr, d), lambda i: (i, 0)),

@@ -91,6 +91,7 @@ def _scatter(c3: jax.Array, parts3: jax.Array, meta: jax.Array, lo: int,
     )
     return pl.pallas_call(
         functools.partial(_kernel, s_total=s_total),
+        name="scatter_add_rows",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, 1, n), c3.dtype),
         interpret=interpret,

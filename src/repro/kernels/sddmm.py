@@ -88,6 +88,7 @@ def _sddmm_call(block_cols, blocks, x3, y3, lo, hi, interpret):
     )
     return pl.pallas_call(
         _kernel,
+        name="bsr_sddmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hi - lo, t_steps, bm, bk), jnp.float32),
         interpret=interpret,

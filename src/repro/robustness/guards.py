@@ -35,6 +35,7 @@ __all__ = [
     "validate_pattern",
     "sampled_finite_check",
     "sampled_finite_check_tree",
+    "start_host_copy",
 ]
 
 # rows sampled per addressable block under check="auto"
@@ -148,6 +149,15 @@ def validate_pattern(snapshot_new, snapshot_expected, *,
             f"instead of attaching mismatched values.")
 
 
+def start_host_copy(c) -> None:
+    """Start copying each addressable piece of C to the host: the pieces
+    that the sweep reads (``addressable_shards`` hands back the same
+    arrays each time), so that the copy follows C on the device instead
+    of waiting for the host to see C ready."""
+    for shard in getattr(c, "addressable_shards", ()):
+        shard.data.copy_to_host_async()
+
+
 def _blocks(c) -> Iterator[Tuple[int, np.ndarray]]:
     """(global_row_offset, host_block) per addressable piece of C."""
     if hasattr(c, "addressable_shards"):
@@ -161,9 +171,10 @@ def _blocks(c) -> Iterator[Tuple[int, np.ndarray]]:
 
 def sampled_finite_check(c, *, mode: Any = "auto",
                          context: str = "DistSpmm",
-                         call_index: Optional[int] = None) -> None:
+                         call_index: Optional[int] = None) -> int:
     """The post-call C sweep: raise ``NumericalFault`` naming the first
-    non-finite element (global row, col) found in the sampled rows.
+    non-finite element (global row, col) found in the sampled rows, or
+    return the bytes of C the sweep copied to the host.
 
     ``"auto"`` samples the corner and strided rows of every addressable
     block (full coverage when a block is small); ``"full"`` checks every
@@ -172,7 +183,9 @@ def sampled_finite_check(c, *, mode: Any = "auto",
     sampling catches the systematic producers (bad operand values, a
     broken backend kernel) cheaply.
     """
+    host_bytes = 0
     for offset, block in _blocks(c):
+        host_bytes += block.nbytes
         if block.ndim == 1:
             block = block[None, :]
         n_rows = block.shape[0]
@@ -198,23 +211,27 @@ def sampled_finite_check(c, *, mode: Any = "auto",
             f"isfinite sweep). The producer is upstream — a poisoned "
             f"operand value or a broken backend kernel; set check=False "
             f"to serve unchecked.")
+    return host_bytes
 
 
 def sampled_finite_check_tree(values, *, mode: Any = "auto",
                               context: str = "DistSpmm",
-                              call_index: Optional[int] = None) -> None:
+                              call_index: Optional[int] = None) -> int:
     """The post-call sweep over a PYTREE of outputs (SDDMM's sampled
     values: one leaf per piece, in the backend's native layout).
 
     Each leaf runs the same row-sampled sweep as C; leaves are viewed as
     2-D (leading dim = rows) so the BSR block layout sweeps too. The
     fault message names the leaf's tree path instead of C's row/col.
+    Returns the bytes the sweep copied to the host.
     """
     import jax
 
+    host_bytes = 0
     for path, leaf in jax.tree_util.tree_leaves_with_path(values):
         label = jax.tree_util.keystr(path)
         for _, block in _blocks(leaf):
+            host_bytes += block.nbytes
             flat = np.asarray(block).reshape(block.shape[0], -1)
             if flat.shape[0] == 0 or flat.shape[1] == 0:
                 continue
@@ -237,3 +254,4 @@ def sampled_finite_check_tree(values, *, mode: Any = "auto",
                 f"isfinite sweep). The producer is upstream — a poisoned "
                 f"X/Y operand value or a broken backend kernel; set "
                 f"check=False to serve unchecked.")
+    return host_bytes

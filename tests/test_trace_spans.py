@@ -1,0 +1,164 @@
+"""The served call's host spans, the guard's byte counter, the executors'
+named scope and the kernels' names (core.trace, core.api, core.dist_spmm,
+kernels/)."""
+import ast
+import contextlib
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core import api
+from repro.core.api import SpmmConfig, compile_spmm
+from repro.core.sparse import power_law_graph
+from repro.distributed.topology import Topology
+from repro.robustness import guards
+
+KERNELS = Path(api.__file__).resolve().parents[1] / "kernels"
+N = 16
+
+
+@pytest.fixture(scope="module")
+def graph():
+    return power_law_graph(256, 2000, seed=3)
+
+
+@pytest.fixture(scope="module")
+def b(graph):
+    return np.random.default_rng(0).standard_normal((graph.shape[1], N)).astype(np.float32)
+
+
+class _Recorder:
+    """Stands in for ``core.trace.span``: records each span's name and the
+    numbers set on it."""
+
+    def __init__(self):
+        self.names, self.stats = [], []
+
+    def __call__(self, name):
+        rec = self
+
+        class _Span:
+            def __enter__(self):
+                rec.names.append(name)
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+            def set_metadata(self, **kw):
+                rec.stats.append((name, kw))
+
+        return _Span()
+
+
+@pytest.mark.parametrize("check,spans", [
+    (False, ["shiro.dispatch"]),
+    ("auto", ["shiro.dispatch", "shiro.wait", "shiro.guard"]),
+])
+def test_call_spans_and_guard_bytes(graph, b, monkeypatch, check, spans):
+    h = compile_spmm(graph, 1, SpmmConfig(check=check))
+    rec = _Recorder()
+    monkeypatch.setattr(api, "span", rec)
+    for i in range(3):
+        c = h(b)
+        assert rec.names == spans * (i + 1)
+        want = (i + 1) * c.nbytes if check else 0
+        assert h.guard_host_bytes == want
+        assert h.stats()["guard_host_bytes"] == want
+    assert h.stats()["calls"] == 3
+    assert rec.stats == ([("shiro.guard", {"host_bytes": c.nbytes})] * 3 if check else [])
+
+
+def test_traced_call_opens_no_span(graph, b, monkeypatch):
+    h = compile_spmm(graph, 1)
+    rec = _Recorder()
+    monkeypatch.setattr(api, "span", rec)
+    jax.jit(lambda x: h(x))(b).block_until_ready()
+    assert rec.names == [] and h.guard_host_bytes == 0
+
+
+def test_spans_leave_c_bit_identical(graph, b, tmp_path):
+    on = compile_spmm(graph, 1)
+    off = compile_spmm(graph, 1, SpmmConfig(check=False))
+    c_plain = np.asarray(off(b))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        c_traced = np.asarray(on(b))
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(c_traced, c_plain)
+    np.testing.assert_array_equal(np.asarray(on(b)), c_plain)
+
+
+@pytest.mark.parametrize("config", [
+    SpmmConfig(schedule="single"),
+    SpmmConfig(schedule=2, overlap=False),
+    SpmmConfig(schedule=2, overlap=True),
+    SpmmConfig(hier=(2, 2), schedule=2, overlap=False),
+    SpmmConfig(hier=(2, 2), schedule=2, overlap=True),
+], ids=["single", "bucketed", "overlap", "hier", "hier-overlap"])
+def test_scopes_are_metadata_only(graph, monkeypatch, config):
+    """The executors' named scope reaches the HLO's op_name metadata and
+    changes nothing else of the compiled program."""
+    where = Topology.local(4)
+    scoped = compile_spmm(graph, where, config)
+    text = scoped._executable(N, np.float32, scoped.default_backend).as_text()
+    assert 'op_name="jit(call)/shiro.spmm/' in text
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare = compile_spmm(graph, where, config)
+        bare_text = bare.lowered_hlo(N)
+    assert "shiro.spmm" not in bare._executable(N, np.float32, bare.default_backend).as_text()
+    assert scoped.lowered_hlo(N) == bare_text
+
+
+def test_every_pallas_call_is_named():
+    names = []
+    for path in sorted(KERNELS.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                kw = {k.arg: k.value for k in node.keywords}
+                assert "name" in kw, f"{path.name}:{node.lineno} pallas_call has no name="
+                names += [c.value for c in ast.walk(kw["name"])
+                          if isinstance(c, ast.Constant) and isinstance(c.value, str)]
+    assert sorted(names) == sorted(["gather_rows", "scatter_add_rows", "bsr_spmm",
+                                    "bsr_spmm_acc", "bsr_sddmm", "rmsnorm"])
+
+
+class _NumpyRecorder:
+    """Stands in for ``guards.np``: records what ``asarray`` reads."""
+
+    def __init__(self):
+        self.read = []
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        self.read.append(a)
+        return np.asarray(a, *args, **kw)
+
+
+@pytest.mark.parametrize("P", [1, 4])
+def test_guard_reads_the_pieces_whose_copy_the_wait_started(graph, b, monkeypatch, P):
+    """``guards.start_host_copy`` pays off only if the sweep reads the same
+    per-shard arrays whose copy it started, in a served call."""
+    h = compile_spmm(graph, P if P == 1 else Topology.local(P))
+    started = []
+    start = guards.start_host_copy
+
+    def recording_start(c):
+        started.extend(s.data for s in c.addressable_shards)
+        start(c)
+
+    rec = _NumpyRecorder()
+    monkeypatch.setattr(guards, "start_host_copy", recording_start)
+    monkeypatch.setattr(guards, "np", rec)
+    c = h(b)
+    assert len(started) == P and len(rec.read) == P
+    assert all(r is s for r, s in zip(rec.read, started))
+    first, again = c.addressable_shards, c.addressable_shards
+    assert all(s.data is t.data for s, t in zip(first, again))
