@@ -237,7 +237,9 @@ class SpmmConfig:
                        are finite at plan/replan time, and runs a cheap
                        SAMPLED ``isfinite`` sweep over each served C —
                        raising ``NumericalFault`` naming the first bad
-                       element/call. ``"full"``/``True`` sweeps every C
+                       element/call. The sweep reduces each shard on its
+                       own device; the host reads back a few scalars per
+                       shard, never C. ``"full"``/``True`` sweeps every C
                        element; ``False`` disables all of it
                        (bit-identical to the unguarded path).
     """
@@ -413,7 +415,7 @@ class DistSpmm:
         self._check = guards.check_mode(config)
         self.calls = 0             # concrete __call__ executions served
         self.numerical_faults = 0  # C sweeps that raised NumericalFault
-        self.guard_host_bytes = 0  # bytes the output sweeps copied to the host
+        self.guard_host_bytes = 0  # bytes the output sweeps read back from devices
         # replicated (1.5D) rungs route by schedule kind: the plan slot
         # holds the s-shard base plan and the exec plan leads [c, s, ...]
         self.replicated = getattr(schedule, "kind", "") == "replicated"
@@ -530,7 +532,14 @@ class DistSpmm:
                                    jnp.dtype(dtype),
                                    sharding=self._in_sharding)
         compiled = fn.lower(self._device_ex(), sds).compile()
+        self._compile_probe(compiled)
         return self._remember(key, compiled)
+
+    def _compile_probe(self, compiled) -> None:
+        """Compile the guard's probe of C beside C's executable, so that
+        neither a first call nor a hot swap's (``warm_from``) compiles it."""
+        if self._check:
+            guards.compile_probe(compiled.out_info, mode=self._check)
 
     def _remember(self, key: Tuple[Any, ...], compiled) -> Any:
         """Cache a fresh executable + fire the lowering hooks."""
@@ -591,6 +600,7 @@ class DistSpmm:
                                   sharding=self._in_sharding)
         compiled = jax.jit(call).lower(self._device_ex(), sx, sy,
                                        sb).compile()
+        self._compile_probe(compiled)
         return self._remember(key, compiled)
 
     def _put(self, arr) -> jax.Array:
@@ -662,7 +672,7 @@ class DistSpmm:
             self._validate_b(b)
             return self._raw_call(b, name)
         # host spans (core.trace): dispatch, then with check on the wait
-        # for C and the guard's sweep
+        # for the guard's device probe of C and the host sweep of its result
         with span("shiro.dispatch"):
             self._validate_b(b)
             b_in = b
@@ -682,18 +692,19 @@ class DistSpmm:
         c = faults.maybe_poison_array(c, site="output")
         if self._check:
             with span("shiro.wait"):
-                guards.start_host_copy(c)
-                jax.block_until_ready(c)
+                # the probe depends on C, so its result is C's readiness too
+                probes, read = guards.read_probes(
+                    guards.probe_finite(c, mode=self._check))
             with span("shiro.guard") as guard:
                 try:
-                    swept = guards.sampled_finite_check(
-                        c, mode=self._check, call_index=self.calls,
+                    guards.raise_nonfinite(
+                        probes, mode=self._check, call_index=self.calls,
                         context=f"DistSpmm(P={self.plan.P}) backend={name!r}")
                 except guards.NumericalFault:
                     self.numerical_faults += 1
                     raise
-                self.guard_host_bytes += swept
-                guard.set_metadata(host_bytes=swept)
+                self.guard_host_bytes += read
+                guard.set_metadata(host_bytes=read)
         return c
 
     def _validate_b(self, b) -> None:
@@ -761,8 +772,9 @@ class DistSpmm:
         The hot-swap contract (``SpmmSession.replan``): the incoming
         handle compiles the outgoing handle's working set BEFORE the
         swap, so the first post-swap wave hits a warm cache instead of
-        paying a lowering on the serving path. Returns the number of
-        executables warmed.
+        paying a lowering on the serving path. Each spmm/fused executable
+        brings the guard's probe of its C (``_compile_probe``). Returns the
+        number of executables warmed.
         """
         warmed = 0
         for key in list(other._executables):

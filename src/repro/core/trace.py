@@ -7,10 +7,11 @@ sibling spans:
 
 * ``shiro.dispatch``: operand validation, placement, the donation copy,
   the executable lookup and the launch;
-* ``shiro.wait``: starting C's copy to the host and waiting for C (only
-  with ``check`` on);
-* ``shiro.guard``: the sampled ``isfinite`` sweep, which copies C to the
-  host; the span carries ``host_bytes``, the bytes that sweep copied.
+* ``shiro.wait``: launching the guard's ``isfinite`` probe of C on the
+  device and reading its result, which waits for C (only with ``check``
+  on);
+* ``shiro.guard``: the host sweep of the probe's scalars; the span
+  carries ``host_bytes``, the bytes read back from devices.
 
 The executors run under ``jax.named_scope("shiro.spmm")`` (see
 ``core.dist_spmm``); that name rides in the HLO's ``op_name`` metadata.

@@ -12,8 +12,14 @@ turns the guards on (default ``"auto"``):
               mismatch, finite-values validation of the sparse operand
               at plan time, and a cheap SAMPLED ``isfinite`` sweep over
               C after each call (corner + strided rows per addressable
-              shard — O(sample) host work, not O(m·n)).
+              shard).
   ``"full"`` / ``True``  the same, but the C sweep checks every element.
+
+The C sweep runs on the device: each addressable shard is reduced on its
+own device by one small jitted probe (``probe_finite``), and the host
+reads back only a few scalars per shard (``read_probes``: the local row
+and column of the first non-finite element and its value, 12 bytes for
+float32), never C itself. Host NumPy arrays are swept on the host.
 
 A failed C sweep raises ``NumericalFault`` naming the first bad element
 and the handle call that produced it; ``SpmmWaveServer`` catches it like
@@ -22,7 +28,8 @@ naming the first bad wave.
 """
 from __future__ import annotations
 
-from typing import Any, Iterator, Optional, Tuple
+import functools
+from typing import Any, Iterator, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -35,7 +42,11 @@ __all__ = [
     "validate_pattern",
     "sampled_finite_check",
     "sampled_finite_check_tree",
-    "start_host_copy",
+    "FiniteProbe",
+    "probe_finite",
+    "compile_probe",
+    "read_probes",
+    "raise_nonfinite",
 ]
 
 # rows sampled per addressable block under check="auto"
@@ -149,24 +160,146 @@ def validate_pattern(snapshot_new, snapshot_expected, *,
             f"instead of attaching mismatched values.")
 
 
-def start_host_copy(c) -> None:
-    """Start copying each addressable piece of C to the host: the pieces
-    that the sweep reads (``addressable_shards`` hands back the same
-    arrays each time), so that the copy follows C on the device instead
-    of waiting for the host to see C ready."""
-    for shard in getattr(c, "addressable_shards", ()):
-        shard.data.copy_to_host_async()
+class FiniteProbe(NamedTuple):
+    """What the sweep reads of one addressable piece of an output."""
+
+    offset: int  # global row of the piece's first row
+    found: Any   # int32 [row, col] of the first non-finite element; row -1 if none
+    value: Any   # that element (or element [0, 0] when none), in the piece's dtype
 
 
-def _blocks(c) -> Iterator[Tuple[int, np.ndarray]]:
-    """(global_row_offset, host_block) per addressable piece of C."""
+def _sample_rows(n_rows: int, full: bool) -> Optional[np.ndarray]:
+    """The rows the sweep reads of a piece with ``n_rows`` rows, or None
+    for every row: all under ``"full"`` and for small pieces, otherwise
+    the corner and strided rows."""
+    if full or n_rows <= _SAMPLE_ROWS:
+        return None
+    return np.unique(np.linspace(0, n_rows - 1, _SAMPLE_ROWS, dtype=np.int64))
+
+
+def _first_nonfinite(x, *, full: bool, vector_is_row: bool, xp):
+    """``(found, value)`` of one piece: the first non-finite element of
+    the rows the mode reads, in ``np.argwhere``'s row-major order, in the
+    piece's 2-D view (leading dim = rows; a vector is one row when
+    ``vector_is_row``). Written once for NumPy and for ``jax.numpy``."""
+    if vector_is_row and x.ndim == 1:
+        x = x[None, :]
+    rows = _sample_rows(x.shape[0], full)
+    sampled = x if rows is None else x[rows]
+    sampled = sampled.reshape(sampled.shape[0], -1)
+    bad = ~xp.isfinite(sampled)
+    row_bad = bad.any(axis=1)
+    r = xp.argmax(row_bad)
+    col = xp.argmax(bad[r])
+    local = r if rows is None else xp.asarray(rows)[r]
+    found = xp.stack([xp.where(row_bad[r], local, -1), col]).astype(xp.int32)
+    return found, sampled[r, col]
+
+
+@functools.cache
+def _device_probe():
+    """The device half of the sweep, one jitted function: its compiled
+    programs are keyed by the piece's shape, dtype and device and by the
+    mode, and the rows come from the static shape while it traces. Built
+    on first use, so that importing this module imports no JAX."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(functools.partial(_first_nonfinite, xp=jnp),
+                   static_argnames=("full", "vector_is_row"))
+
+
+def _pieces(c) -> Iterator[Tuple[int, Any]]:
+    """(global_row_offset, piece) per addressable piece of C: each shard's
+    own device array, or the host array itself."""
     if hasattr(c, "addressable_shards"):
         for shard in c.addressable_shards:
             rows = shard.index[0] if shard.index else slice(None)
             start = rows.start if getattr(rows, "start", None) else 0
-            yield int(start), np.asarray(shard.data)
+            yield int(start), shard.data
     else:
         yield 0, np.asarray(c)
+
+
+def _is_empty(shape: Tuple[int, ...], vector_is_row: bool) -> bool:
+    if vector_is_row and len(shape) == 1:
+        return shape[0] == 0
+    return shape[0] == 0 or int(np.prod(shape[1:])) == 0
+
+
+def probe_finite(c, *, mode: Any = "auto",
+                 vector_is_row: bool = True) -> List[FiniteProbe]:
+    """Launch the sweep over every addressable piece of ``c``.
+
+    A device piece is reduced on its own device, so no byte of ``c``
+    moves between chips or to the host; a host array is reduced on the
+    host. Read the results with ``read_probes``.
+    """
+    full = mode in ("full", True)
+    probes = []
+    for offset, piece in _pieces(c):
+        if _is_empty(tuple(piece.shape), vector_is_row):
+            continue
+        if isinstance(piece, np.ndarray):
+            found, value = _first_nonfinite(piece, full=full,
+                                            vector_is_row=vector_is_row,
+                                            xp=np)
+        else:
+            found, value = _device_probe()(piece, full=full,
+                                           vector_is_row=vector_is_row)
+        probes.append(FiniteProbe(offset, found, value))
+    return probes
+
+
+def compile_probe(out, *, mode: Any = "auto") -> None:
+    """Compile the device sweep for every addressable piece of an output
+    described by ``out`` (a ``jax.ShapeDtypeStruct`` with its sharding),
+    so that the first call it guards compiles nothing."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    shape = out.sharding.shard_shape(out.shape)
+    if _is_empty(tuple(shape), True):
+        return
+    for device in out.sharding.addressable_devices:
+        piece = jax.ShapeDtypeStruct(shape, out.dtype,
+                                     sharding=SingleDeviceSharding(device))
+        _device_probe().lower(piece, full=mode in ("full", True),
+                              vector_is_row=True).compile()
+
+
+def read_probes(probes: List[FiniteProbe]) -> Tuple[List[FiniteProbe], int]:
+    """Wait for the probes and read them to the host in one
+    ``jax.device_get``; returns them with the bytes read from devices."""
+    import jax
+
+    read = sum(a.nbytes for p in probes for a in (p.found, p.value)
+               if isinstance(a, jax.Array))
+    return jax.device_get(probes), read
+
+
+def _mode_name(mode: Any) -> str:
+    return "full" if mode in ("full", True) else "auto"
+
+
+def raise_nonfinite(probes: List[FiniteProbe], *, mode: Any = "auto",
+                    context: str = "DistSpmm",
+                    call_index: Optional[int] = None) -> None:
+    """The host half of the C sweep: raise ``NumericalFault`` naming the
+    first non-finite element (global row, col) that the read probes
+    found, piece by piece."""
+    for p in probes:
+        row, col = (int(v) for v in p.found)
+        if row < 0:
+            continue
+        val = np.asarray(p.value)[()]
+        at = f" on call #{call_index}" if call_index is not None else ""
+        raise NumericalFault(
+            f"{context}: non-finite C[{p.offset + row}, {col}] = {val!r}{at} "
+            f"(check={_mode_name(mode)} "
+            f"isfinite sweep). The producer is upstream — a poisoned "
+            f"operand value or a broken backend kernel; set check=False "
+            f"to serve unchecked.")
 
 
 def sampled_finite_check(c, *, mode: Any = "auto",
@@ -174,7 +307,7 @@ def sampled_finite_check(c, *, mode: Any = "auto",
                          call_index: Optional[int] = None) -> int:
     """The post-call C sweep: raise ``NumericalFault`` naming the first
     non-finite element (global row, col) found in the sampled rows, or
-    return the bytes of C the sweep copied to the host.
+    return the bytes the sweep read back from devices.
 
     ``"auto"`` samples the corner and strided rows of every addressable
     block (full coverage when a block is small); ``"full"`` checks every
@@ -183,35 +316,9 @@ def sampled_finite_check(c, *, mode: Any = "auto",
     sampling catches the systematic producers (bad operand values, a
     broken backend kernel) cheaply.
     """
-    host_bytes = 0
-    for offset, block in _blocks(c):
-        host_bytes += block.nbytes
-        if block.ndim == 1:
-            block = block[None, :]
-        n_rows = block.shape[0]
-        if n_rows == 0:
-            continue
-        if mode in ("full", True) or n_rows <= _SAMPLE_ROWS:
-            rows = np.arange(n_rows)
-        else:
-            rows = np.unique(np.linspace(0, n_rows - 1, _SAMPLE_ROWS,
-                                         dtype=np.int64))
-        sampled = block[rows]
-        finite = np.isfinite(sampled)
-        if finite.all():
-            continue
-        where = np.argwhere(~finite)[0]
-        r = int(offset + rows[int(where[0])])
-        col = int(where[1]) if sampled.ndim > 1 else 0
-        val = sampled[tuple(where)]
-        at = f" on call #{call_index}" if call_index is not None else ""
-        raise NumericalFault(
-            f"{context}: non-finite C[{r}, {col}] = {val!r}{at} "
-            f"(check={'full' if mode in ('full', True) else 'auto'} "
-            f"isfinite sweep). The producer is upstream — a poisoned "
-            f"operand value or a broken backend kernel; set check=False "
-            f"to serve unchecked.")
-    return host_bytes
+    probes, read = read_probes(probe_finite(c, mode=mode))
+    raise_nonfinite(probes, mode=mode, context=context, call_index=call_index)
+    return read
 
 
 def sampled_finite_check_tree(values, *, mode: Any = "auto",
@@ -223,35 +330,26 @@ def sampled_finite_check_tree(values, *, mode: Any = "auto",
     Each leaf runs the same row-sampled sweep as C; leaves are viewed as
     2-D (leading dim = rows) so the BSR block layout sweeps too. The
     fault message names the leaf's tree path instead of C's row/col.
-    Returns the bytes the sweep copied to the host.
+    Every leaf's probes are read back together; returns the bytes read.
     """
     import jax
 
-    host_bytes = 0
+    labels, probes = [], []
     for path, leaf in jax.tree_util.tree_leaves_with_path(values):
-        label = jax.tree_util.keystr(path)
-        for _, block in _blocks(leaf):
-            host_bytes += block.nbytes
-            flat = np.asarray(block).reshape(block.shape[0], -1)
-            if flat.shape[0] == 0 or flat.shape[1] == 0:
-                continue
-            if mode in ("full", True) or flat.shape[0] <= _SAMPLE_ROWS:
-                rows = np.arange(flat.shape[0])
-            else:
-                rows = np.unique(np.linspace(0, flat.shape[0] - 1,
-                                             _SAMPLE_ROWS, dtype=np.int64))
-            sampled = flat[rows]
-            finite = np.isfinite(sampled)
-            if finite.all():
-                continue
-            where = np.argwhere(~finite)[0]
-            val = sampled[tuple(where)]
-            at = f" on call #{call_index}" if call_index is not None else ""
-            raise NumericalFault(
-                f"{context}: non-finite sampled value {val!r} in output "
-                f"leaf {label!r}{at} "
-                f"(check={'full' if mode in ('full', True) else 'auto'} "
-                f"isfinite sweep). The producer is upstream — a poisoned "
-                f"X/Y operand value or a broken backend kernel; set "
-                f"check=False to serve unchecked.")
-    return host_bytes
+        for p in probe_finite(leaf, mode=mode, vector_is_row=False):
+            labels.append(jax.tree_util.keystr(path))
+            probes.append(p)
+    probes, read = read_probes(probes)
+    for label, p in zip(labels, probes):
+        if int(p.found[0]) < 0:
+            continue
+        val = np.asarray(p.value)[()]
+        at = f" on call #{call_index}" if call_index is not None else ""
+        raise NumericalFault(
+            f"{context}: non-finite sampled value {val!r} in output "
+            f"leaf {label!r}{at} "
+            f"(check={_mode_name(mode)} "
+            f"isfinite sweep). The producer is upstream — a poisoned "
+            f"X/Y operand value or a broken backend kernel; set "
+            f"check=False to serve unchecked.")
+    return read

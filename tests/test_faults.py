@@ -155,6 +155,56 @@ def test_sampled_finite_check_modes():
         guards.sampled_finite_check(c, mode="full", context="t")
 
 
+def _host_sweep(c, mode):
+    """The sweep as a plain host loop over full copies of C's shards: the
+    (global row, col, value) of the first non-finite element it reads."""
+    for shard in c.addressable_shards:
+        block = np.asarray(shard.data)
+        n_rows = block.shape[0]
+        if mode == "full" or n_rows <= guards._SAMPLE_ROWS:
+            rows = np.arange(n_rows)
+        else:
+            rows = np.unique(np.linspace(0, n_rows - 1, guards._SAMPLE_ROWS, dtype=np.int64))
+        bad = np.argwhere(~np.isfinite(block[rows]))
+        if bad.size:
+            r, col = bad[0]
+            return int(shard.index[0].start or 0) + int(rows[r]), int(col), block[rows[r], col]
+    return None
+
+
+@pytest.mark.parametrize("mode", ["auto", "full"])
+@pytest.mark.parametrize("P", [1, 4])
+def test_device_sweep_finds_what_the_host_sweep_found(mode, P):
+    """The device probe reads the rows the host sweep read and names the
+    same element, with its global row, in every shard."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    mesh = jax.make_mesh((P,), ("x",), devices=jax.devices()[:P])
+    sharding = NamedSharding(mesh, PartitionSpec("x"))
+    rng = np.random.default_rng(P)
+    rows_per_shard = 256 // P
+    for trial in range(12):
+        host = np.ones((256, 8), np.float32)
+        for _ in range(trial % 3 + 1):  # one to three bad elements
+            host[rng.integers(256), rng.integers(8)] = (np.nan, np.inf, -np.inf)[trial % 3]
+        if P > 1 and trial == 0:  # a sampled row of a shard after the first
+            host[:] = 1.0
+            host[2 * rows_per_shard + 2, 5] = np.nan
+        c = jax.device_put(host, sharding)
+        want = _host_sweep(c, mode)
+        if want is None:
+            assert guards.sampled_finite_check(c, mode=mode, context="t") == P * 12
+            continue
+        row, col, val = want
+        with pytest.raises(NumericalFault) as err:
+            guards.sampled_finite_check(c, mode=mode, context="t", call_index=trial)
+        assert str(err.value) == (
+            f"t: non-finite C[{row}, {col}] = {val!r} on call #{trial} (check={mode} "
+            f"isfinite sweep). The producer is upstream — a poisoned operand value or a "
+            f"broken backend kernel; set check=False to serve unchecked.")
+
+
 def test_validate_sparse_values_names_index(power_law_matrix):
     import dataclasses
 
@@ -261,6 +311,24 @@ def test_nan_poison_output_raises_numerical_fault(power_law_matrix):
     unchecked = compile_spmm(a, 4, SpmmConfig(schedule="auto", check=False))
     with inject([Fault(kind="nan_poison", site="output")]):
         assert np.isnan(np.asarray(unchecked(b))[0, 0])
+
+
+@pytest.mark.parametrize("kernel,P", [("spmm", 1), ("spmm", 4), ("sddmm", 4)])
+def test_nan_poison_output_caught_by_device_sweep(power_law_matrix, kernel, P):
+    """A poisoned served output raises through the device probe, and the
+    guard reads back only the probe's scalars."""
+    a = power_law_matrix()
+    handle = compile_spmm(a, P, SpmmConfig(kernel=kernel))
+    operands = (_b(),) if kernel == "spmm" else (_b(64, 8, 1), _b(64, 8, 2))
+    handle(*operands)  # healthy first
+    read = handle.guard_host_bytes
+    assert 0 < read < 1024
+    match = r"C\[0, 0\] = np.float32\(nan\) on call #2" if kernel == "spmm" else "output leaf"
+    with inject([Fault(kind="nan_poison", site="output")]):
+        with pytest.raises(NumericalFault, match=match):
+            handle(*operands)
+    assert handle.stats()["numerical_faults"] == 1
+    assert handle.guard_host_bytes == read  # counted for sweeps that pass
 
 
 def test_nan_poison_output_server_retries_to_success(power_law_matrix):

@@ -25,6 +25,7 @@ from repro.kernels.bsr_spmm import bsr_spmm_acc_pallas, bsr_spmm_pallas
 from repro.kernels.gather_rows import gather_rows_pallas
 from repro.kernels.scatter_add_rows import scatter_add_rows_sorted_pallas
 from repro.kernels.sddmm import bsr_sddmm_pallas
+from repro.robustness import guards
 
 ROWS = 169_343  # ogbn-arxiv nodes
 N = 128  # ogbn-arxiv feature width
@@ -108,3 +109,13 @@ def test_p4_flat_executor_compiles(topo, monkeypatch):
     hlo = h.lowered_hlo(N)
     assert "tpu_custom_call" in hlo
     assert " collective-permute" in hlo
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["auto", "full"])
+def test_guard_probe_compiles(full, one_chip):
+    """The served call's isfinite probe over one shard of C at arxiv's
+    size; it reads C in place and moves nothing between chips."""
+    c = _sds((ROWS, N), jnp.float32, one_chip)
+    text = guards._device_probe().lower(c, full=full, vector_is_row=True).compile().as_text()
+    assert not [op for op in ("all-gather", "all-reduce", "all-to-all",
+                              "collective-permute") if op in text]

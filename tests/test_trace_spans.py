@@ -1,6 +1,6 @@
-"""The served call's host spans, the guard's byte counter, the executors'
-named scope and the kernels' names (core.trace, core.api, core.dist_spmm,
-kernels/)."""
+"""The served call's host spans, the guard's byte counter and device probe,
+the executors' named scope and the kernels' names (core.trace, core.api,
+robustness.guards, core.dist_spmm, kernels/)."""
 import ast
 import contextlib
 from pathlib import Path
@@ -62,13 +62,13 @@ def test_call_spans_and_guard_bytes(graph, b, monkeypatch, check, spans):
     rec = _Recorder()
     monkeypatch.setattr(api, "span", rec)
     for i in range(3):
-        c = h(b)
+        h(b)
         assert rec.names == spans * (i + 1)
-        want = (i + 1) * c.nbytes if check else 0
+        want = (i + 1) * PROBE_BYTES if check else 0
         assert h.guard_host_bytes == want
         assert h.stats()["guard_host_bytes"] == want
     assert h.stats()["calls"] == 3
-    assert rec.stats == ([("shiro.guard", {"host_bytes": c.nbytes})] * 3 if check else [])
+    assert rec.stats == ([("shiro.guard", {"host_bytes": PROBE_BYTES})] * 3 if check else [])
 
 
 def test_traced_call_opens_no_span(graph, b, monkeypatch):
@@ -128,37 +128,40 @@ def test_every_pallas_call_is_named():
                                     "bsr_spmm_acc", "bsr_sddmm", "rmsnorm"])
 
 
-class _NumpyRecorder:
-    """Stands in for ``guards.np``: records what ``asarray`` reads."""
+# bytes of one probe result: the int32 (row, col) pair and a float32 value
+PROBE_BYTES = 2 * 4 + 4
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all", "collective-permute",
+               "reduce-scatter", "send(", "recv(")
 
-    def __init__(self):
-        self.read = []
 
-    def __getattr__(self, name):
-        return getattr(np, name)
-
-    def asarray(self, a, *args, **kw):
-        self.read.append(a)
-        return np.asarray(a, *args, **kw)
+def _where(P):
+    return P if P == 1 else Topology.local(P)
 
 
 @pytest.mark.parametrize("P", [1, 4])
-def test_guard_reads_the_pieces_whose_copy_the_wait_started(graph, b, monkeypatch, P):
-    """``guards.start_host_copy`` pays off only if the sweep reads the same
-    per-shard arrays whose copy it started, in a served call."""
-    h = compile_spmm(graph, P if P == 1 else Topology.local(P))
-    started = []
-    start = guards.start_host_copy
+def test_served_call_reads_back_only_the_probe(graph, P):
+    """The guard reads a few scalars per shard, whatever C's size."""
+    h = compile_spmm(graph, _where(P))
+    read = []
+    for n in (N, 8 * N):
+        wide = np.random.default_rng(n).standard_normal((graph.shape[1], n)).astype(np.float32)
+        before = h.guard_host_bytes
+        c = h(wide)
+        read.append(h.guard_host_bytes - before)
+        assert len(c.addressable_shards) == P
+    assert read[0] == read[1] == P * PROBE_BYTES < 1024
 
-    def recording_start(c):
-        started.extend(s.data for s in c.addressable_shards)
-        start(c)
 
-    rec = _NumpyRecorder()
-    monkeypatch.setattr(guards, "start_host_copy", recording_start)
-    monkeypatch.setattr(guards, "np", rec)
+@pytest.mark.parametrize("mode", ["auto", "full"])
+def test_probe_runs_on_each_shard_with_no_collective(graph, b, mode):
+    """At P=4 each shard is reduced on its own device, by a program with no
+    collective: no byte of C moves between chips."""
+    h = compile_spmm(graph, Topology.local(4), SpmmConfig(check=mode))
     c = h(b)
-    assert len(started) == P and len(rec.read) == P
-    assert all(r is s for r, s in zip(rec.read, started))
-    first, again = c.addressable_shards, c.addressable_shards
-    assert all(s.data is t.data for s, t in zip(first, again))
+    probes = guards.probe_finite(c, mode=mode)
+    assert len(probes) == 4
+    for shard, p in zip(c.addressable_shards, probes):
+        assert p.found.devices() == p.value.devices() == {shard.device}
+        text = guards._device_probe().lower(
+            shard.data, full=mode == "full", vector_is_row=True).compile().as_text()
+        assert not [op for op in COLLECTIVES if op in text], text
