@@ -54,7 +54,14 @@ cumulative-prefix contract).
 
 Every executor runs under ``jax.named_scope("shiro.spmm")``, so that a
 profile attributes its device ops, forward and backward, through the
-HLO's ``op_name`` metadata. The scope is metadata only: the compiled
+HLO's ``op_name`` metadata. Inside every ``shard_map`` body each step
+runs under a scope of its own, named once in ``core.trace``: ``pack``
+(``pack_rows_op``, the gather of B rows into the send buffer),
+``exchange`` (the all_to_all, ppermute, psum_scatter and all_gather
+calls, ``_exchange_segments`` included, with the slicing and relayout of
+their buffers), ``compute`` (the backend's diag, colp and rowp pieces)
+and ``aggregate`` (``scatter_add_rows_exec_op``). A profile reads them
+as ``shiro.spmm/.../<step>``. The scopes are metadata only: the compiled
 program is unchanged.
 """
 from __future__ import annotations
@@ -81,6 +88,7 @@ from .local_backend import (
     coo_spmm_local, get_backend,
 )
 from .planner import SpmmPlan, local_piece_csrs
+from .trace import AGGREGATE, COMPUTE, EXCHANGE, PACK
 
 __all__ = [
     "BackendSpec",
@@ -600,25 +608,31 @@ def flat_spmm(plan: FlatExecPlan, b_global: jax.Array, mesh: Mesh,
             n = b_loc.shape[1]
 
             # ① pack + exchange B rows (column-based comm, Fig. 1(b))
-            send_b = pack_rows_op(b_loc, b_send_idx)  # [P, max_b, N]
-            recv_b = all_to_all(send_b, axis, 0, 0, tiled=False)
+            with jax.named_scope(PACK):
+                send_b = pack_rows_op(b_loc, b_send_idx)  # [P, max_b, N]
+            with jax.named_scope(EXCHANGE):
+                recv_b = all_to_all(send_b, axis, 0, 0, tiled=False)
 
             # ② remote computation (row-based, Fig. 1(c)): partial C rows
             #    for every other process, against the LOCAL B block.
-            partials = be.compute(pieces["rowp"], b_loc,
-                                  P_ * plan.max_c)  # [P*max_c, N]
-            send_c = partials.reshape(P_, plan.max_c, n)
-            recv_c = all_to_all(send_c, axis, 0, 0, tiled=False)
+            with jax.named_scope(COMPUTE):
+                partials = be.compute(pieces["rowp"], b_loc,
+                                      P_ * plan.max_c)  # [P*max_c, N]
+            with jax.named_scope(EXCHANGE):
+                send_c = partials.reshape(P_, plan.max_c, n)
+                recv_c = all_to_all(send_c, axis, 0, 0, tiled=False)
 
             # ③ local compute: diagonal + column-covered remote nonzeros
-            c = be.compute(pieces["diag"], b_loc, m_local)
-            recv_b_flat = recv_b.reshape(P_ * plan.max_b, n)
-            c = c + be.compute(pieces["colp"], recv_b_flat, m_local)
+            with jax.named_scope(COMPUTE):
+                c = be.compute(pieces["diag"], b_loc, m_local)
+                recv_b_flat = recv_b.reshape(P_ * plan.max_b, n)
+                c = c + be.compute(pieces["colp"], recv_b_flat, m_local)
 
             # ④ result aggregation: scatter received partial C rows
-            return scatter_add_rows_exec_op(
-                c, recv_c.reshape(P_ * plan.max_c, n),
-                c_recv_rows.reshape(-1), agg_perm, agg_meta)
+            with jax.named_scope(AGGREGATE):
+                return scatter_add_rows_exec_op(
+                    c, recv_c.reshape(P_ * plan.max_c, n),
+                    c_recv_rows.reshape(-1), agg_perm, agg_meta)
     elif not overlap:
         b_segments: Segments = plan.meta["b_segments"]
         c_segments: Segments = plan.meta["c_segments"]
@@ -635,23 +649,29 @@ def flat_spmm(plan: FlatExecPlan, b_global: jax.Array, mesh: Mesh,
 
             # ① pack once, then one ppermute per scheduled shift — each
             #   padded only to its round's slot ceiling
-            send_b = pack_rows_op(b_loc, b_send_idx)  # [R_b, N]
-            recv_b = _exchange_segments(b_segments, axis, P_, R_b, n,
-                                        b_loc.dtype, _slice_fetch(send_b))
+            with jax.named_scope(PACK):
+                send_b = pack_rows_op(b_loc, b_send_idx)  # [R_b, N]
+            with jax.named_scope(EXCHANGE):
+                recv_b = _exchange_segments(b_segments, axis, P_, R_b, n,
+                                            b_loc.dtype, _slice_fetch(send_b))
 
             # ② partial C rows, computed straight into the bucketed
             #   send space, then exchanged shift by shift
-            partials = be.compute(pieces["rowp"], b_loc, R_c)  # [R_c, N]
-            recv_c = _exchange_segments(c_segments, axis, P_, R_c, n,
-                                        b_loc.dtype, _slice_fetch(partials))
+            with jax.named_scope(COMPUTE):
+                partials = be.compute(pieces["rowp"], b_loc, R_c)  # [R_c, N]
+            with jax.named_scope(EXCHANGE):
+                recv_c = _exchange_segments(c_segments, axis, P_, R_c, n,
+                                            b_loc.dtype, _slice_fetch(partials))
 
             # ③ local compute against the bucketed receive space
-            c = be.compute(pieces["diag"], b_loc, m_local)
-            c = c + be.compute(pieces["colp"], recv_b, m_local)
+            with jax.named_scope(COMPUTE):
+                c = be.compute(pieces["diag"], b_loc, m_local)
+                c = c + be.compute(pieces["colp"], recv_b, m_local)
 
             # ④ aggregation of received partials
-            return scatter_add_rows_exec_op(
-                c, recv_c, c_recv_rows, agg_perm, agg_meta)
+            with jax.named_scope(AGGREGATE):
+                return scatter_add_rows_exec_op(
+                    c, recv_c, c_recv_rows, agg_perm, agg_meta)
     else:
         if not plan.meta.get("overlap_ready"):
             raise ValueError(
@@ -672,38 +692,44 @@ def flat_spmm(plan: FlatExecPlan, b_global: jax.Array, mesh: Mesh,
             #   unrolled permutes are mutually independent, so the async
             #   collective scheduler keeps round k+1 on the wire while
             #   round k's segment compute (step ④) runs
-            send_b = pack_rows_op(b_loc, b_send_idx)  # [R_b, N]
-            recv_b = [ppermute(jax.lax.slice_in_dim(send_b, off, off + slot),
-                               axis, _shift_perm(P_, d))
-                      for d, off, slot in b_segments]
+            with jax.named_scope(PACK):
+                send_b = pack_rows_op(b_loc, b_send_idx)  # [R_b, N]
+            with jax.named_scope(EXCHANGE):
+                recv_b = [ppermute(jax.lax.slice_in_dim(send_b, off, off + slot),
+                                   axis, _shift_perm(P_, d))
+                          for d, off, slot in b_segments]
 
             # ② per-round partial-C compute feeding its own round's wire:
             #   round i's permute departs after only ITS rowp slice ran
             recv_c = []
             for i, (d, off, slot) in enumerate(c_segments):
-                part = be.compute(pieces[f"rowp@{i}"], b_loc, slot)
-                recv_c.append(ppermute(part, axis, _shift_perm(P_, d)))
+                with jax.named_scope(COMPUTE):
+                    part = be.compute(pieces[f"rowp@{i}"], b_loc, slot)
+                with jax.named_scope(EXCHANGE):
+                    recv_c.append(ppermute(part, axis, _shift_perm(P_, d)))
 
-            # ③ diagonal block while the first rounds fly
-            c = be.compute(pieces["diag"], b_loc, m_local)
+            with jax.named_scope(COMPUTE):
+                # ③ diagonal block while the first rounds fly
+                c = be.compute(pieces["diag"], b_loc, m_local)
 
-            # ④ consume B rounds as they land: cumulative receive prefix
-            #   + segment-accumulating compute (bit-identical to staged)
-            colp_acc = jnp.zeros((m_local, n), b_loc.dtype)
-            prefix = None
-            for i, seg in enumerate(recv_b):
-                prefix = seg if prefix is None else jnp.concatenate(
-                    [prefix, seg], axis=0)
-                colp_acc = backend_compute_segment(
-                    be, pieces[f"colp@{i}"], prefix, colp_acc)
-            c = c + colp_acc
+                # ④ consume B rounds as they land: cumulative receive prefix
+                #   + segment-accumulating compute (bit-identical to staged)
+                colp_acc = jnp.zeros((m_local, n), b_loc.dtype)
+                prefix = None
+                for i, seg in enumerate(recv_b):
+                    prefix = seg if prefix is None else jnp.concatenate(
+                        [prefix, seg], axis=0)
+                    colp_acc = backend_compute_segment(
+                        be, pieces[f"colp@{i}"], prefix, colp_acc)
+                c = c + colp_acc
 
             # ⑤ per-round aggregation of received partials
-            for i, (d, off, slot) in enumerate(c_segments):
-                c = scatter_add_rows_exec_op(
-                    c, recv_c[i],
-                    jax.lax.slice_in_dim(c_recv_rows, off, off + slot),
-                    seg_agg[f"perm@{i}"], seg_agg[f"meta@{i}"])
+            with jax.named_scope(AGGREGATE):
+                for i, (d, off, slot) in enumerate(c_segments):
+                    c = scatter_add_rows_exec_op(
+                        c, recv_c[i],
+                        jax.lax.slice_in_dim(c_recv_rows, off, off + slot),
+                        seg_agg[f"perm@{i}"], seg_agg[f"meta@{i}"])
             return c
 
     fn = shard_map(body, mesh=mesh,
@@ -755,40 +781,46 @@ def hier_spmm(plan: HierExecPlan, b_global: jax.Array, mesh: Mesh,
 
             # Stage I.① (inter-group, column-based): ship de-duplicated B
             # rows once per destination group. Pairs (g, l) <-> (g', l).
-            send_bg = pack_rows_op(b_loc, b_group_send_idx)  # [G, max_bg, N]
-            recv_bg = all_to_all(send_bg, group_axis, 0, 0, tiled=False)
+            with jax.named_scope(PACK):
+                send_bg = pack_rows_op(b_loc, b_group_send_idx)  # [G, max_bg, N]
+            with jax.named_scope(EXCHANGE):
+                recv_bg = all_to_all(send_bg, group_axis, 0, 0, tiled=False)
 
             # Stage I.① (intra-group, row-based): compute partials and
             # pre-aggregate within the source group via reduce-scatter;
             # each member ends up owning the aggregates for destinations
             # that share its local rank (the "representative" of Fig. 6(e)).
-            partials = be.compute(pieces["rowp"], b_loc,
-                                  G * L * max_cg)  # [(gd,ld,slot), N]
-            partials = partials.reshape(G, L * max_cg, n)
-            agg = psum_scatter(partials, local_axis,
-                               scatter_dimension=1, tiled=True)
-            # agg: [G(dst), max_cg, N] — aggregated partials for dests
-            # sharing my local rank.
+            with jax.named_scope(COMPUTE):
+                partials = be.compute(pieces["rowp"], b_loc,
+                                      G * L * max_cg)  # [(gd,ld,slot), N]
+            with jax.named_scope(EXCHANGE):
+                partials = partials.reshape(G, L * max_cg, n)
+                agg = psum_scatter(partials, local_axis,
+                                   scatter_dimension=1, tiled=True)
+                # agg: [G(dst), max_cg, N] — aggregated partials for dests
+                # sharing my local rank.
 
-            # Stage II.② (inter-group, row-based): aggregated C rows cross
-            # the slow tier once per source group.
-            recv_cg = all_to_all(agg, group_axis, 0, 0, tiled=False)
+                # Stage II.② (inter-group, row-based): aggregated C rows
+                # cross the slow tier once per source group.
+                recv_cg = all_to_all(agg, group_axis, 0, 0, tiled=False)
 
-            # Stage II.② (intra-group, column-based): distribute fetched B
-            # rows inside the destination group.
-            all_bg = jax.lax.all_gather(recv_bg, local_axis, axis=0,
-                                        tiled=False)
-            # all_bg: [L(src), G(src), max_bg, N]
+                # Stage II.② (intra-group, column-based): distribute
+                # fetched B rows inside the destination group.
+                all_bg = jax.lax.all_gather(recv_bg, local_axis, axis=0,
+                                            tiled=False)
+                # all_bg: [L(src), G(src), max_bg, N]
 
             # local compute
-            c = be.compute(pieces["diag"], b_loc, m_local)
-            bg_flat = all_bg.reshape(L * G * max_bg, n)
-            c = c + be.compute(pieces["colp"], bg_flat, m_local)
+            with jax.named_scope(COMPUTE):
+                c = be.compute(pieces["diag"], b_loc, m_local)
+                bg_flat = all_bg.reshape(L * G * max_bg, n)
+                c = c + be.compute(pieces["colp"], bg_flat, m_local)
 
             # result aggregation of row-based partials
-            c = scatter_add_rows_exec_op(
-                c, recv_cg.reshape(G * max_cg, n),
-                c_recv_rows.reshape(-1), agg_perm, agg_meta)
+            with jax.named_scope(AGGREGATE):
+                c = scatter_add_rows_exec_op(
+                    c, recv_cg.reshape(G * max_cg, n),
+                    c_recv_rows.reshape(-1), agg_perm, agg_meta)
             return c[None]
     elif not overlap:
         bg_segments: Segments = plan.meta["bg_segments"]
@@ -808,44 +840,51 @@ def hier_spmm(plan: HierExecPlan, b_global: jax.Array, mesh: Mesh,
 
             # Stage I.① inter-group B fetch, one ppermute per group shift;
             # shift 0 (own group) is a wire-free local slice
-            send_bg = pack_rows_op(b_loc, b_send_flat)  # [R_bg, N]
-            recv_bg = _exchange_segments(bg_segments, group_axis, G, R_bg,
-                                         n, b_loc.dtype,
-                                         _slice_fetch(send_bg),
-                                         local=local_b)
+            with jax.named_scope(PACK):
+                send_bg = pack_rows_op(b_loc, b_send_flat)  # [R_bg, N]
+            with jax.named_scope(EXCHANGE):
+                recv_bg = _exchange_segments(bg_segments, group_axis, G, R_bg,
+                                             n, b_loc.dtype,
+                                             _slice_fetch(send_bg),
+                                             local=local_b)
 
             # Stage I.① intra-group pre-aggregation (unchanged): rowp rows
             # are laid out shift-major — (dg·L + ld)·max_cg + slot — so
             # the aggregated tile for group shift dg sits at agg[dg]
-            partials = be.compute(pieces["rowp"], b_loc, G * L * max_cg)
-            partials = partials.reshape(G, L * max_cg, n)
-            agg = psum_scatter(partials, local_axis,
-                               scatter_dimension=1, tiled=True)
-            # agg: [G(shift), max_cg, N]
+            with jax.named_scope(COMPUTE):
+                partials = be.compute(pieces["rowp"], b_loc, G * L * max_cg)
+            with jax.named_scope(EXCHANGE):
+                partials = partials.reshape(G, L * max_cg, n)
+                agg = psum_scatter(partials, local_axis,
+                                   scatter_dimension=1, tiled=True)
+                # agg: [G(shift), max_cg, N]
 
-            # Stage II.② inter-group C transfer, bucketed per shift: the
-            # send buffer for shift dg is the pre-aggregated tile agg[dg]
-            recv_cg = _exchange_segments(
-                cg_segments, group_axis, G, R_cg, n, b_loc.dtype,
-                lambda dg, off, slot: jax.lax.slice_in_dim(agg[dg], 0, slot),
-                local=local_c)
+                # Stage II.② inter-group C transfer, bucketed per shift:
+                # the send buffer for shift dg is the pre-aggregated tile
+                # agg[dg]
+                recv_cg = _exchange_segments(
+                    cg_segments, group_axis, G, R_cg, n, b_loc.dtype,
+                    lambda dg, off, slot: jax.lax.slice_in_dim(agg[dg], 0, slot),
+                    local=local_c)
 
-            # Stage II.② intra-group B distribution; the gathered buffer
-            # is re-laid SEGMENT-major ([L·off, L·(off+slot)) per group
-            # shift) to match the colp index space — the order the
-            # overlapped executor consumes segments in, so both paths
-            # accumulate identically
-            all_bg = jax.lax.all_gather(recv_bg, local_axis, axis=0,
-                                        tiled=False)  # [L, R_bg, N]
-            gparts = [all_bg[:, off:off + slot, :].reshape(L * slot, n)
-                      for _, off, slot in bg_all]
-            gathered = (jnp.concatenate(gparts, axis=0) if gparts
-                        else jnp.zeros((L * R_bg, n), b_loc.dtype))
+                # Stage II.② intra-group B distribution; the gathered
+                # buffer is re-laid SEGMENT-major ([L·off, L·(off+slot))
+                # per group shift) to match the colp index space — the
+                # order the overlapped executor consumes segments in, so
+                # both paths accumulate identically
+                all_bg = jax.lax.all_gather(recv_bg, local_axis, axis=0,
+                                            tiled=False)  # [L, R_bg, N]
+                gparts = [all_bg[:, off:off + slot, :].reshape(L * slot, n)
+                          for _, off, slot in bg_all]
+                gathered = (jnp.concatenate(gparts, axis=0) if gparts
+                            else jnp.zeros((L * R_bg, n), b_loc.dtype))
 
-            c = be.compute(pieces["diag"], b_loc, m_local)
-            c = c + be.compute(pieces["colp"], gathered, m_local)
-            c = scatter_add_rows_exec_op(
-                c, recv_cg, c_recv_flat, agg_perm, agg_meta)
+            with jax.named_scope(COMPUTE):
+                c = be.compute(pieces["diag"], b_loc, m_local)
+                c = c + be.compute(pieces["colp"], gathered, m_local)
+            with jax.named_scope(AGGREGATE):
+                c = scatter_add_rows_exec_op(
+                    c, recv_cg, c_recv_flat, agg_perm, agg_meta)
             return c[None]
     else:
         if not plan.meta.get("overlap_ready"):
@@ -865,51 +904,60 @@ def hier_spmm(plan: HierExecPlan, b_global: jax.Array, mesh: Mesh,
 
             # Stage I.① inter-group B fetch, issued round by round; the
             # shift-0 own-group segment never touches the wire
-            send_bg = pack_rows_op(b_loc, b_send_flat)  # [R_bg, N]
+            with jax.named_scope(PACK):
+                send_bg = pack_rows_op(b_loc, b_send_flat)  # [R_bg, N]
             b_segs = []
-            for dg, off, slot in bg_all:
-                seg = jax.lax.slice_in_dim(send_bg, off, off + slot)
-                if dg != 0:
-                    seg = ppermute(seg, group_axis, _shift_perm(G, dg))
-                b_segs.append(seg)
+            with jax.named_scope(EXCHANGE):
+                for dg, off, slot in bg_all:
+                    seg = jax.lax.slice_in_dim(send_bg, off, off + slot)
+                    if dg != 0:
+                        seg = ppermute(seg, group_axis, _shift_perm(G, dg))
+                    b_segs.append(seg)
 
             # Stage I.① intra-group pre-aggregation, one reduce-scatter
             # per consumed group shift — round dg's inter-group C
             # transfer departs as soon as ITS tile is aggregated, while
             # the remaining shifts are still reducing (Alg. 1's
             # "inter-group ∥ intra-group" made explicit in dataflow)
-            partials = be.compute(pieces["rowp"], b_loc, G * L * max_cg)
-            partials = partials.reshape(G, L * max_cg, n)
+            with jax.named_scope(COMPUTE):
+                partials = be.compute(pieces["rowp"], b_loc, G * L * max_cg)
             c_segs = []
-            for dg, off, slot in cg_all:
-                agg_dg = psum_scatter(partials[dg], local_axis,
-                                      scatter_dimension=0, tiled=True)
-                seg = jax.lax.slice_in_dim(agg_dg, 0, slot)
-                if dg != 0:
-                    seg = ppermute(seg, group_axis, _shift_perm(G, dg))
-                c_segs.append(seg)
+            with jax.named_scope(EXCHANGE):
+                partials = partials.reshape(G, L * max_cg, n)
+                for dg, off, slot in cg_all:
+                    agg_dg = psum_scatter(partials[dg], local_axis,
+                                          scatter_dimension=0, tiled=True)
+                    seg = jax.lax.slice_in_dim(agg_dg, 0, slot)
+                    if dg != 0:
+                        seg = ppermute(seg, group_axis, _shift_perm(G, dg))
+                    c_segs.append(seg)
 
             # Stage II: own-group compute first (overlaps the in-flight
             # fetch rounds), then consume each gathered slab as it lands
-            c = be.compute(pieces["diag"], b_loc, m_local)
-            colp_acc = jnp.zeros((m_local, n), b_loc.dtype)
+            with jax.named_scope(COMPUTE):
+                c = be.compute(pieces["diag"], b_loc, m_local)
+                colp_acc = jnp.zeros((m_local, n), b_loc.dtype)
             prefix = None
             for i, seg in enumerate(b_segs):
-                gathered = jax.lax.all_gather(
-                    seg, local_axis, axis=0, tiled=False)
-                gathered = gathered.reshape(-1, n)  # [L·slot, N]
-                prefix = gathered if prefix is None else jnp.concatenate(
-                    [prefix, gathered], axis=0)
-                colp_acc = backend_compute_segment(
-                    be, pieces[f"colp@{i}"], prefix, colp_acc)
-            c = c + colp_acc
+                with jax.named_scope(EXCHANGE):
+                    gathered = jax.lax.all_gather(
+                        seg, local_axis, axis=0, tiled=False)
+                    gathered = gathered.reshape(-1, n)  # [L·slot, N]
+                with jax.named_scope(COMPUTE):
+                    prefix = gathered if prefix is None else jnp.concatenate(
+                        [prefix, gathered], axis=0)
+                    colp_acc = backend_compute_segment(
+                        be, pieces[f"colp@{i}"], prefix, colp_acc)
+            with jax.named_scope(COMPUTE):
+                c = c + colp_acc
 
             # per-round aggregation of the inter-group partials
-            for i, (dg, off, slot) in enumerate(cg_all):
-                c = scatter_add_rows_exec_op(
-                    c, c_segs[i],
-                    jax.lax.slice_in_dim(c_recv_flat, off, off + slot),
-                    seg_agg[f"perm@{i}"], seg_agg[f"meta@{i}"])
+            with jax.named_scope(AGGREGATE):
+                for i, (dg, off, slot) in enumerate(cg_all):
+                    c = scatter_add_rows_exec_op(
+                        c, c_segs[i],
+                        jax.lax.slice_in_dim(c_recv_flat, off, off + slot),
+                        seg_agg[f"perm@{i}"], seg_agg[f"meta@{i}"])
             return c[None]
 
     gl = P(group_axis, local_axis)
@@ -987,24 +1035,31 @@ def replicated_spmm(plan: ReplicatedExecPlan, b_global: jax.Array,
         n = b_loc.shape[1]
 
         # ① pack + lane-exchange B rows, one joint ppermute per round
-        send_b = pack_rows_op(b_loc, b_send_idx)  # [R_b, N]
-        recv_b = _exchange(b_rounds, send_b, R_b, n, b_loc.dtype)
+        with jax.named_scope(PACK):
+            send_b = pack_rows_op(b_loc, b_send_idx)  # [R_b, N]
+        with jax.named_scope(EXCHANGE):
+            recv_b = _exchange(b_rounds, send_b, R_b, n, b_loc.dtype)
 
         # ② partial C rows for this lane's shifts, exchanged per round
-        partials = be.compute(pieces["rowp"], b_loc, R_c)  # [R_c, N]
-        recv_c = _exchange(c_rounds, partials, R_c, n, b_loc.dtype)
+        with jax.named_scope(COMPUTE):
+            partials = be.compute(pieces["rowp"], b_loc, R_c)  # [R_c, N]
+        with jax.named_scope(EXCHANGE):
+            recv_c = _exchange(c_rounds, partials, R_c, n, b_loc.dtype)
 
         # ③ lane-local compute: diagonal (lane 0 only, by construction)
         #   + this lane's column-covered nonzeros
-        c = be.compute(pieces["diag"], b_loc, m_local)
-        c = c + be.compute(pieces["colp"], recv_b, m_local)
+        with jax.named_scope(COMPUTE):
+            c = be.compute(pieces["diag"], b_loc, m_local)
+            c = c + be.compute(pieces["colp"], recv_b, m_local)
 
         # ④ aggregate received partials, then sum + scatter the lanes'
         #   C blocks over the replica axis
-        c = scatter_add_rows_exec_op(
-            c, recv_c, c_recv_rows, agg_perm, agg_meta)
-        return psum_scatter(c, replica_axis, scatter_dimension=0,
-                            tiled=True)  # [m_local / c, N]
+        with jax.named_scope(AGGREGATE):
+            c = scatter_add_rows_exec_op(
+                c, recv_c, c_recv_rows, agg_perm, agg_meta)
+        with jax.named_scope(EXCHANGE):
+            return psum_scatter(c, replica_axis, scatter_dimension=0,
+                                tiled=True)  # [m_local / c, N]
 
     rx = P(replica_axis, axis)
     fn = shard_map(body, mesh=mesh,

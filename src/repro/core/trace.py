@@ -15,12 +15,19 @@ sibling spans:
 
 The executors run under ``jax.named_scope("shiro.spmm")`` (see
 ``core.dist_spmm``); that name rides in the HLO's ``op_name`` metadata.
+Inside their ``shard_map`` bodies each step runs under one more
+``jax.named_scope``, named by ``STEPS`` (``core.dist_spmm`` says what each
+holds). The names carry no ``shiro.`` prefix, so that a profile still
+counts a step's ops as the executor's.
 """
 from __future__ import annotations
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["span"]
+__all__ = ["span", "PACK", "EXCHANGE", "COMPUTE", "AGGREGATE", "STEPS"]
+
+PACK, EXCHANGE, COMPUTE, AGGREGATE = "pack", "exchange", "compute", "aggregate"
+STEPS = (PACK, EXCHANGE, COMPUTE, AGGREGATE)
 
 
 def span(name: str) -> TraceAnnotation:

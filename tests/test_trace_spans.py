@@ -3,6 +3,7 @@ the executors' named scope and the kernels' names (core.trace, core.api,
 robustness.guards, core.dist_spmm, kernels/)."""
 import ast
 import contextlib
+import re
 from pathlib import Path
 
 import jax
@@ -12,6 +13,7 @@ import pytest
 from repro.core import api
 from repro.core.api import SpmmConfig, compile_spmm
 from repro.core.sparse import power_law_graph
+from repro.core.trace import STEPS
 from repro.distributed.topology import Topology
 from repro.robustness import guards
 
@@ -98,10 +100,13 @@ def test_spans_leave_c_bit_identical(graph, b, tmp_path):
     SpmmConfig(schedule=2, overlap=True),
     SpmmConfig(hier=(2, 2), schedule=2, overlap=False),
     SpmmConfig(hier=(2, 2), schedule=2, overlap=True),
-], ids=["single", "bucketed", "overlap", "hier", "hier-overlap"])
+    SpmmConfig(hier=(2, 2), schedule="single"),
+    SpmmConfig(replicate=2),
+], ids=["single", "bucketed", "overlap", "hier", "hier-overlap", "hier-single", "replicated"])
 def test_scopes_are_metadata_only(graph, monkeypatch, config):
-    """The executors' named scope reaches the HLO's op_name metadata and
-    changes nothing else of the compiled program."""
+    """The executors' named scopes, ``shiro.spmm`` and its steps, reach the
+    HLO's op_name metadata and change nothing else of the compiled
+    program."""
     where = Topology.local(4)
     scoped = compile_spmm(graph, where, config)
     text = scoped._executable(N, np.float32, scoped.default_backend).as_text()
@@ -112,6 +117,37 @@ def test_scopes_are_metadata_only(graph, monkeypatch, config):
         bare_text = bare.lowered_hlo(N)
     assert "shiro.spmm" not in bare._executable(N, np.float32, bare.default_backend).as_text()
     assert scoped.lowered_hlo(N) == bare_text
+
+
+# every shard_map body of the executors, by the handle options that select
+# it at P=4, and the (strategy, schedule, overlap) the handle then reports
+BODIES = {
+    "single": ({"schedule": "single"}, ("flat", "single", False)),
+    "bucketed": ({"schedule": 2, "overlap": False}, ("flat", "bucketed", False)),
+    "overlap": ({"schedule": 2, "overlap": True}, ("flat", "bucketed", True)),
+    "hier-single": ({"hier": (2, 2), "schedule": "single"}, ("hier", "single", False)),
+    "hier": ({"hier": (2, 2), "schedule": 2, "overlap": False}, ("hier", "bucketed", False)),
+    "hier-overlap": ({"hier": (2, 2), "schedule": 2, "overlap": True},
+                     ("hier", "bucketed", True)),
+    "replicated": ({"replicate": 2}, ("replicated", "replicated", False)),
+}
+
+
+@pytest.mark.parametrize("body", list(BODIES))
+def test_every_executor_body_names_its_steps(graph, b, body):
+    """At P=4 each body runs pack, exchange, compute and aggregate under
+    scopes of those names below ``shiro.spmm``, and C still matches the
+    float32 reference."""
+    options, want = BODIES[body]
+    h = compile_spmm(graph, Topology.local(4), **options)
+    st = h.stats()
+    assert (h.strategy, st["schedule_kind"], st["overlap"]) == want
+    text = h._executable(N, np.float32, h.default_backend).as_text()
+    names = set(re.findall(r'op_name="(jit\(call\)/shiro\.spmm/[^"]*)"', text))
+    for step in STEPS:
+        assert any(f"/{step}/" in name for name in names), step
+    ref = graph.to_dense().astype(np.float64) @ b
+    np.testing.assert_allclose(np.asarray(h(b)), ref, rtol=2e-4, atol=2e-4)
 
 
 def test_every_pallas_call_is_named():
