@@ -93,7 +93,7 @@ from .dist_spmm import (
     replicated_exec_arrays, replicated_spmm,
 )
 from .hierarchy import HierPlan, build_hier_plan
-from .local_backend import get_backend
+from .local_backend import fold_counts, get_backend
 from .planner import SpmmPlan, Strategy, build_plan, replicate_plan
 from .sparse import CSRMatrix, PatternSnapshot
 from .trace import span
@@ -919,6 +919,14 @@ class DistSpmm:
             guard_host_bytes=self.guard_host_bytes,
             numerical_faults=self.numerical_faults,
         )
+        # the coo product's row fold, over every prepared piece of the
+        # default backend (per-round segments too): the share of their
+        # stored nonzeros it reduces, and its slots over those nonzeros
+        stored, folded, slots = (sum(c) for c in zip(*(
+            fold_counts(p)
+            for p in self.ex.pieces[self.default_backend].values())))
+        out["fold_share"] = folded / stored if stored else None
+        out["fold_pad"] = slots / folded if folded else None
         out.setdefault("decision_source", "model")
         out.setdefault("measured_time", None)
         out.setdefault("replicate", 1)

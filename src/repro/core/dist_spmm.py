@@ -25,11 +25,11 @@ metadata, so ``flat_spmm`` / ``hier_spmm`` calls are unchanged.
 
 Local compute is pluggable too (core.local_backend): each exec plan
 carries the planner's sparse pieces prepared in one or more backend
-layouts (padded COO scatter-add, Pallas ELL/BSR blocks, ...), and the
-executors take ``backend="coo"|"bsr"`` per call. Neither the backend nor
-the pack/aggregate kernels touch the communication schedule — the
-collectives in the lowered HLO are identical whichever backend computes
-the local pieces.
+layouts (COO with a degree-bucketed row fold, Pallas ELL/BSR blocks,
+...), and the executors take ``backend="coo"|"bsr"`` per call. Neither
+the backend nor the pack/aggregate kernels touch the communication
+schedule — the collectives in the lowered HLO are identical whichever
+backend computes the local pieces.
 
 The send-buffer pack and the received-partials aggregation go through
 ``kernels.ops`` (``pack_rows_op`` / ``scatter_add_rows_exec_op``): the

@@ -21,8 +21,15 @@ lowered collectives are bit-identical across backends.
 
 Built-ins:
 
-* ``CooBackend`` — padded COO gather + segment scatter-add. XLA fuses it
-  well on CPU and it tolerates arbitrary shapes; the portable default.
+* ``CooBackend`` — padded COO plus a degree-bucketed row layout built at
+  prepare time. The product folds each row's nonzeros into that row, in
+  COO order, one slot after another, and gathers the bucket outputs back
+  into row order: no scatter-add into C and no per-call sort of row ids.
+  The per-row addition chain is the COO scatter-add's, so C, staged or
+  per round, matches ``coo_spmm_local`` bit for bit on the CPU. Its
+  derivative is the COO expression (``kernels.ops.
+  coo_accumulate_rows_op``). It tolerates arbitrary shapes; the portable
+  default.
 * ``BsrBackend`` — ELL block layout feeding the Pallas MXU kernel
   (kernels.bsr_spmm). ``interpret=None`` auto-selects interpret mode off
   TPU; ``impl="ref"`` forces the pure-jnp oracle (kernels.ref).
@@ -56,6 +63,8 @@ __all__ = [
     "backend_sddmm",
     "backend_with_values",
     "coo_sddmm_local",
+    "RowFold",
+    "fold_counts",
 ]
 
 Piece = Dict[str, jax.Array]
@@ -226,36 +235,185 @@ def _stack_coo(csrs: List[CSRMatrix]) -> Tuple[np.ndarray, np.ndarray, np.ndarra
     return row, col, val
 
 
+# The row fold's bucket widths: one per degree up to _FOLD_EXACT, then
+# four steps an octave up to 32 and two (1.5x, 2x) an octave above. A
+# piece keeps only the widths its rows' degrees occupy.
+_FOLD_EXACT = 16
+
+
+def _fold_ladder(max_deg: int) -> np.ndarray:
+    """Every bucket width a piece of row degree <= ``max_deg`` may use."""
+    out = list(range(1, _FOLD_EXACT + 1))
+    while out[-1] < max_deg:
+        base = out[-1]  # a power of two
+        steps = (1.25, 1.5, 1.75, 2) if base < 32 else (1.5, 2)
+        out.extend(int(base * s) for s in steps)
+    return np.asarray(out, np.int64)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RowFold:
+    """A piece's degree-bucketed row layout (``coo_accumulate_rows_op``).
+
+    Buckets lie one after another in each array; ``buckets`` gives each
+    one's width ``w`` and row count ``R``. Every array leads with the
+    piece's process axes, if any:
+
+    * ``perm`` [m]: each row of C's bucket output row, in the buckets'
+      concatenated outputs, or -1 for a row with no nonzeros;
+    * ``rows`` [sum R]: each bucket's row ids;
+    * ``src``, ``col``, ``val`` [sum w·R]: per bucket, slot-major ([w, R]
+      flattened), for slot ``k`` of each row the COO index of the
+      nonzero it folds, in the row's COO order, that nonzero's column and
+      its value;
+    * ``nnz`` []: the stored nonzeros.
+
+    Six flat arrays, however many buckets: each is an argument of every
+    executable that runs the piece, and each argument costs host time at
+    dispatch.
+    """
+
+    perm: jax.Array
+    rows: jax.Array
+    src: jax.Array
+    col: jax.Array
+    val: jax.Array
+    nnz: jax.Array
+    buckets: Tuple[Tuple[int, int], ...] = dataclasses.field(
+        metadata=dict(static=True), default=())
+
+    def with_values(self, vals: jax.Array) -> "RowFold":
+        """The layout with each slot's value read anew from ``vals``
+        [..., nnz]; padding slots read 0."""
+        pad = jnp.zeros(vals.shape[:-1] + (1,), vals.dtype)
+        ext = jnp.concatenate([vals, pad], axis=-1)
+        return dataclasses.replace(
+            self, val=jnp.take_along_axis(ext, self.src, axis=-1))
+
+
+def _fold_layout(csrs: List[CSRMatrix], val: np.ndarray) -> RowFold:
+    """The ``RowFold`` of stacked COO pieces; ``val`` is their stacked
+    values, [P, nnz_max].
+
+    Rows with nonzeros go to buckets of ladder widths. A padding slot
+    points at index ``nnz_max``, one past the stored values, holds the
+    value 0 and repeats its row's last column, so it adds an exact zero
+    and reads no row of B the real slots do not.
+
+    Buckets share one row count across processes. The counts are the
+    fewest that hold every process's rows when each row takes the
+    smallest width that holds it or, where its own bucket is full, a
+    wider one: bucket ``j`` holds as many rows as the process with the
+    most rows of width ``>= ladder[j]`` has beyond the wider buckets'
+    counts. With one process each row takes its smallest width. A bucket
+    of 64 rows or more that the device folds unrolled rounds its rows up
+    to the (8, 128) tile's 8 sublanes, so that its terms, gathered flat,
+    are a [w, R, N] view with no relayout. Padding rows are never
+    gathered back.
+    """
+    from ..kernels.ops import FOLD_UNROLL
+
+    coos = [c.to_coo() for c in csrs]
+    P_, pad = val.shape
+    m = max((c.shape[0] for c in csrs), default=0)
+    degs = [np.bincount(c.row, minlength=m) for c in coos]
+    ladder = _fold_ladder(max((int(d.max(initial=0)) for d in degs),
+                              default=0))
+    rungs = [np.where(d > 0, np.searchsorted(ladder, d), -1) for d in degs]
+    counts = np.stack([np.bincount(r[r >= 0], minlength=ladder.size)
+                       for r in rungs])
+    # rows that need width >= ladder[j], most over processes
+    need = counts[:, ::-1].cumsum(axis=1)[:, ::-1].max(axis=0)
+    sizes = need - np.append(need[1:], 0)
+    sizes = np.where((ladder <= FOLD_UNROLL) & (sizes >= 64),
+                     -(-sizes // 8) * 8, sizes)
+    buckets = tuple((int(ladder[j]), int(n)) for j, n in enumerate(sizes)
+                    if n)
+    row_off = np.cumsum([0] + [n for _, n in buckets])
+    slot_off = np.cumsum([0] + [w * n for w, n in buckets])
+    perm = np.full((P_, m), -1, np.int32)
+    rows_a = np.zeros((P_, row_off[-1]), np.int32)
+    src_a = np.full((P_, slot_off[-1]), pad, np.int32)
+    col_a = np.zeros((P_, slot_off[-1]), np.int32)
+    for p, c in enumerate(coos):
+        # widest rows first into the widest buckets, then down the ladder
+        order = np.argsort(c.row, kind="stable")
+        start = np.cumsum(degs[p]) - degs[p]
+        filled = np.nonzero(rungs[p] >= 0)[0]
+        filled = filled[np.argsort(-rungs[p][filled], kind="stable")]
+        hi = 0
+        for i in reversed(range(len(buckets))):
+            w, n = buckets[i]
+            rows = np.sort(filled[hi:hi + n])
+            hi += rows.size
+            d = degs[p][rows]
+            k = np.arange(w)[:, None]
+            e = order[start[rows] + np.minimum(k, d - 1)]  # [w, rows]
+            src = np.full((w, n), pad, np.int32)
+            col = np.zeros((w, n), np.int32)
+            src[:, :rows.size] = np.where(k < d, e, pad)
+            col[:, :rows.size] = c.col[e]
+            rows_a[p, row_off[i]:row_off[i] + rows.size] = rows
+            src_a[p, slot_off[i]:slot_off[i + 1]] = src.ravel()
+            col_a[p, slot_off[i]:slot_off[i + 1]] = col.ravel()
+            perm[p, rows] = row_off[i] + np.arange(rows.size)
+    val_ext = np.concatenate([val, np.zeros((P_, 1), val.dtype)], axis=1)
+    return RowFold(perm=perm, rows=rows_a, src=src_a, col=col_a,
+                   val=np.take_along_axis(val_ext, src_a, axis=1),
+                   nnz=np.array([c.nnz for c in coos], np.int32),
+                   buckets=buckets)
+
+
+def fold_counts(piece: Piece) -> Tuple[int, int, int]:
+    """(stored nonzeros, those the row fold reduces, slots it folds) of a
+    prepared piece. A piece without a fold layout counts its nonzero
+    values."""
+    fold = piece.get("fold")
+    if fold is not None:
+        nnz = int(np.asarray(fold.nnz).sum())
+        return nnz, nnz, int(fold.src.size)
+    leaves = [np.asarray(x) for x in jax.tree_util.tree_leaves(piece)]
+    return sum(int(np.count_nonzero(x)) for x in leaves
+               if np.issubdtype(x.dtype, np.floating)), 0, 0
+
+
 @dataclasses.dataclass(frozen=True)
 class CooBackend:
-    """Padded-COO gather + segment scatter-add (today's executor compute)."""
+    """Padded COO plus a degree-bucketed row layout; the product folds
+    each row's nonzeros in order (``coo_accumulate_rows_op``)."""
 
     name: ClassVar[str] = "coo"
 
     def prepare(self, csrs: List[CSRMatrix]) -> Piece:
         row, col, val = _stack_coo(csrs)
+        fold = _fold_layout(csrs, val)
         return {"row": jnp.asarray(row), "col": jnp.asarray(col),
-                "val": jnp.asarray(val)}
+                "val": jnp.asarray(val),
+                "fold": jax.tree_util.tree_map(jnp.asarray, fold)}
 
     def compute(self, piece: Piece, b: jax.Array, m_out: int) -> jax.Array:
-        return coo_spmm_local(piece["row"], piece["col"], piece["val"],
-                              b, m_out)
+        from ..kernels.ops import coo_accumulate_rows_op
+
+        return coo_accumulate_rows_op(
+            jnp.zeros((m_out, b.shape[1]), b.dtype), piece["fold"],
+            piece["row"], piece["col"], piece["val"], b, True)
 
     def compute_segment(self, piece: Piece, b_prefix: jax.Array,
                         acc: jax.Array) -> jax.Array:
-        # scatter straight into the running accumulator — the same
-        # gather/scatter-add chain the staged compute runs, resumed
+        # the row fold resumed from the running accumulator: the same
+        # per-row addition chain the staged compute runs from zeros
         from ..kernels.ops import coo_accumulate_rows_op
 
-        return coo_accumulate_rows_op(acc, piece["row"], piece["col"],
-                                      piece["val"], b_prefix)
+        return coo_accumulate_rows_op(acc, piece["fold"], piece["row"],
+                                      piece["col"], piece["val"], b_prefix)
 
     def sddmm(self, piece: Piece, x: jax.Array, y: jax.Array) -> jax.Array:
         return coo_sddmm_local(piece["row"], piece["col"], piece["val"],
                                x, y)
 
     def with_values(self, piece: Piece, vals: jax.Array) -> Piece:
-        return dict(piece, val=vals)
+        return dict(piece, val=vals, fold=piece["fold"].with_values(vals))
 
 
 # ---------------------------------------------------------------------------

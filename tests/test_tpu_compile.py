@@ -18,11 +18,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.api import compile_spmm
+from repro.core.local_backend import CooBackend
 from repro.core.sparse import power_law_graph
 from repro.distributed.topology import Topology
 from repro.kernels import ops, prefetch
 from repro.kernels.bsr_spmm import bsr_spmm_acc_pallas, bsr_spmm_pallas
 from repro.kernels.gather_rows import gather_rows_pallas
+from repro.kernels.ops import FOLD_UNROLL, fold_rows
 from repro.kernels.scatter_add_rows import scatter_add_rows_sorted_pallas
 from repro.kernels.sddmm import bsr_sddmm_pallas
 from repro.robustness import guards
@@ -96,8 +98,9 @@ def test_p4_flat_executor_compiles(topo, monkeypatch):
     bucketed schedule's collective permutes, with the Pallas row kernels
     packing and aggregating the exchanged rows."""
     # code that asks jax.default_backend() sees this host's CPU: steer the
-    # kernel dispatch to the chip's path
+    # kernel dispatch and the coo row fold to the chip's path
     monkeypatch.setattr(ops, "kernel_backend", lambda: "pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n = 8192
     a = power_law_graph(n, 8 * n, alpha=0.7, seed=0)
     described = Topology(kind="local", devices=tuple(topo.devices),
@@ -119,3 +122,20 @@ def test_guard_probe_compiles(full, one_chip):
     text = guards._device_probe().lower(c, full=full, vector_is_row=True).compile().as_text()
     assert not [op for op in ("all-gather", "all-reduce", "all-to-all",
                               "collective-permute") if op in text]
+
+
+def test_row_fold_compiles(one_chip):
+    """The coo product's row fold on the chip's path: unrolled chains for
+    narrow buckets, loops for rows wider than FOLD_UNROLL, and no
+    scatter or sort."""
+    n = 32768
+    a = power_law_graph(n, 8 * n, alpha=0.7, seed=0)
+    assert np.diff(a.indptr).max() > FOLD_UNROLL
+    fold = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape[1:], x.dtype, one_chip),
+        CooBackend().prepare([a])["fold"])
+    c = _sds((n, N), jnp.float32, one_chip)
+    text = fold_rows.lower(c, fold, c, fused=True,
+                           zero_acc=True).compile().as_text()
+    assert " while(" in text
+    assert not [op for op in (" scatter(", " sort(") if op in text]
